@@ -38,6 +38,8 @@ import pathlib
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import common
 
 #: Tiny scale for the CI smoke profile: every fact shrinks to its 8-row
@@ -201,6 +203,7 @@ def main(argv=None) -> None:
             sys.exit(1)
         print(f"no regressions beyond {100 * args.threshold:.0f}%")
         return
+    enable_compile_cache()
     json_dir = pathlib.Path(args.json_out) if args.json_out else None
     if json_dir is not None:
         json_dir.mkdir(parents=True, exist_ok=True)

@@ -9,6 +9,7 @@ heads (cardinality = #groups, the runtime statistic of the stage).
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Sequence, Tuple
 
 import jax
@@ -20,49 +21,61 @@ from .table import Table
 AGG_OPS = ("sum", "count", "min", "max", "mean")
 
 
-def _local_group_agg(key: jax.Array, valid: jax.Array,
-                     cols: Dict[str, jax.Array],
-                     aggs: Sequence[Tuple[str, str]]):
-    """Aggregate one partition by key. Returns (out_cols, out_valid)."""
-    n = key.shape[0]
+def _local_segments(key: jax.Array, valid: jax.Array):
+    """Group one partition by key: the sort order, each sorted row's group
+    id, the live (valid) sorted rows, the group-head rows that carry the
+    results, and the group keys at those heads."""
     big = jnp.iinfo(jnp.int32).max
     k = jnp.where(valid, key, big).astype(jnp.int32)
     order = jnp.argsort(k)
     ks = k[order]
     head = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
     seg = jnp.cumsum(head.astype(jnp.int32)) - 1          # group id per row
-    out_valid = head & (ks != big)
+    live = ks != big
+    out_valid = head & live
+    return order, seg, live, out_valid, jnp.where(out_valid, ks, 0)
 
-    out_cols = {"_group_key": jnp.where(out_valid, ks, 0)}
-    live = (ks != big)
-    for col, op in aggs:
-        v = cols[col][order]
-        if op == "count":
-            data = live.astype(jnp.int32)
-            seg_out = jax.ops.segment_sum(data, seg, num_segments=n)
-        elif op in ("sum", "mean"):
-            data = jnp.where(live, v, 0)
-            seg_out = jax.ops.segment_sum(data, seg, num_segments=n)
-            if op == "mean":
-                cnt = jax.ops.segment_sum(live.astype(v.dtype), seg,
-                                          num_segments=n)
-                seg_out = seg_out / jnp.maximum(cnt, 1)
-        elif op == "min":
-            data = jnp.where(live, v, jnp.asarray(jnp.inf, v.dtype)
-                             if jnp.issubdtype(v.dtype, jnp.floating)
-                             else jnp.iinfo(v.dtype).max)
-            seg_out = jax.ops.segment_min(data, seg, num_segments=n)
-        elif op == "max":
-            data = jnp.where(live, v, jnp.asarray(-jnp.inf, v.dtype)
-                             if jnp.issubdtype(v.dtype, jnp.floating)
-                             else jnp.iinfo(v.dtype).min)
-            seg_out = jax.ops.segment_max(data, seg, num_segments=n)
-        else:
-            raise ValueError(f"unknown agg op {op}")
-        # Each row reads its group's aggregate; only head rows stay valid.
-        out_cols[f"{op}_{col}"] = jnp.take(seg_out, seg)
-    # Head rows carry the group results; others are invalid.
-    return out_cols, out_valid
+
+def _local_agg_column(v: jax.Array, order: jax.Array, seg: jax.Array,
+                      live: jax.Array, op: str) -> jax.Array:
+    """One aggregate of one partition's column over its key groups; each
+    row reads its group's result (only head rows stay valid)."""
+    n = v.shape[0]
+    v = v[order]
+    if op == "count":
+        seg_out = jax.ops.segment_sum(live.astype(jnp.int32), seg,
+                                      num_segments=n)
+    elif op in ("sum", "mean"):
+        data = jnp.where(live, v, 0)
+        seg_out = jax.ops.segment_sum(data, seg, num_segments=n)
+        if op == "mean":
+            cnt = jax.ops.segment_sum(live.astype(v.dtype), seg,
+                                      num_segments=n)
+            seg_out = seg_out / jnp.maximum(cnt, 1)
+    elif op == "min":
+        data = jnp.where(live, v, jnp.asarray(jnp.inf, v.dtype)
+                         if jnp.issubdtype(v.dtype, jnp.floating)
+                         else jnp.iinfo(v.dtype).max)
+        seg_out = jax.ops.segment_min(data, seg, num_segments=n)
+    elif op == "max":
+        data = jnp.where(live, v, jnp.asarray(-jnp.inf, v.dtype)
+                         if jnp.issubdtype(v.dtype, jnp.floating)
+                         else jnp.iinfo(v.dtype).min)
+        seg_out = jax.ops.segment_max(data, seg, num_segments=n)
+    else:
+        raise ValueError(f"unknown agg op {op}")
+    return jnp.take(seg_out, seg)
+
+
+# One program per shape (and per dtype and op), whatever the columns are
+# called: the group-by compiles once for every query of a shape.
+_segments = jax.jit(jax.vmap(_local_segments))
+
+
+@functools.partial(jax.jit, static_argnames=("op",))
+def _agg_column(v, order, seg, live, op: str) -> jax.Array:
+    return jax.vmap(lambda *a: _local_agg_column(*a, op))(v, order, seg,
+                                                          live)
 
 
 def group_aggregate(table: Table, key: str,
@@ -73,11 +86,12 @@ def group_aggregate(table: Table, key: str,
     if not table.stacked:
         raise ValueError("group_aggregate expects a stacked table")
     shuffled, report = shuffle(table, key, capacity_factor)
-    out_cols, out_valid = jax.vmap(
-        lambda k, v, c: _local_group_agg(k, v, c, tuple(aggs))
-    )(shuffled.column(key), shuffled.valid, shuffled.columns)
-    out_cols = dict(out_cols)
-    out_cols[key] = out_cols.pop("_group_key")
+    order, seg, live, out_valid, group_key = _segments(
+        shuffled.column(key), shuffled.valid)
+    out_cols = {f"{op}_{col}": _agg_column(shuffled.column(col), order, seg,
+                                           live, op)
+                for col, op in aggs}
+    out_cols[key] = group_key
     # Output is hash-partitioned by the group key: downstream shuffles on
     # the same key are elided (§3.7 key-dependency).
     return Table(out_cols, out_valid, partitioned_by=key), report

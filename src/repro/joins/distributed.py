@@ -47,8 +47,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core.psts import key_set
-from ..kernels.bloom import _positions
-from ..kernels.zone_map import _HI_IDENT, _LO_IDENT, merge_ranges
+from ..kernels import ops as kops
 from .local_join import hash_join, sort_join
 from .methods import HypercubeSpec
 from .slots import (SHUFFLE_SEED, gather_rows, hash32, pair_capacity,
@@ -57,18 +56,10 @@ from .table import Table
 
 AXIS = "p"
 
-# jax.shard_map became a top-level API only after 0.4.x; fall back to the
-# experimental home so the distributed tier runs on the pinned toolchain.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def make_join_mesh(p: int) -> Mesh:
     """1-D mesh over the join parallelism p."""
-    from ..launch.mesh import _axis_type_kwargs
-    return jax.make_mesh((p,), (AXIS,), **_axis_type_kwargs(1))
+    return jax.make_mesh((p,), (AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def cube_axis_names(n_axes: int) -> tuple[str, ...]:
@@ -82,9 +73,8 @@ def make_cube_mesh(dims: tuple[int, ...]) -> Mesh:
     ``hypercube_shuffle``'s flat cell index). A flat mesh is the degenerate
     cube ``(p, 1, ..., 1)`` — same devices, same program, share-1 axes make
     their collectives identities."""
-    from ..launch.mesh import _axis_type_kwargs
     return jax.make_mesh(tuple(dims), cube_axis_names(len(dims)),
-                         **_axis_type_kwargs(len(dims)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(dims))
 
 
 def place_cube(table: Table, mesh: Mesh) -> Table:
@@ -155,7 +145,7 @@ def dist_shuffle_hash_join(a: Table, b: Table, a_key: str, b_key: str,
         out_cols, out_valid = _attach(ra_cols, ra_valid, rb_cols, res)
         return ({n: c[None] for n, c in out_cols.items()}, out_valid[None])
 
-    cols, valid = _shard_map(
+    cols, valid = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS)),
@@ -180,7 +170,7 @@ def dist_shuffle_sort_join(a: Table, b: Table, a_key: str, b_key: str,
         out_cols, out_valid = _attach(ra_cols, ra_valid, rb_cols, res)
         return ({n: c[None] for n, c in out_cols.items()}, out_valid[None])
 
-    cols, valid = _shard_map(
+    cols, valid = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS)),
@@ -250,7 +240,7 @@ def dist_hypercube_join(tables: tuple, spec: HypercubeSpec, mesh: Mesh,
         return ({n: c[None] for n, c in cols.items()}, valid[None])
 
     spec_all = P(names)
-    cols, valid = _shard_map(
+    cols, valid = jax.shard_map(
         f, mesh=mesh,
         in_specs=(spec_all, spec_all),
         out_specs=(spec_all, spec_all),
@@ -260,31 +250,12 @@ def dist_hypercube_join(tables: tuple, spec: HypercubeSpec, mesh: Mesh,
 
 # -- distributed runtime-filter build ----------------------------------------
 
-def _partial_bloom_words(keys: jax.Array, valid: jax.Array, m_bits: int,
-                         k: int) -> jax.Array:
-    """Partial bloom filter of one partition's live keys: a dense jnp
-    build (scatter is fine outside Pallas) sharing ``_positions`` with the
-    kernel pair, so partial ORs compose to the exact global bit array."""
-    flat = keys.reshape(-1).astype(jnp.int32)
-    v = valid.reshape(-1)
-    bits = jnp.zeros((m_bits,), jnp.bool_)
-    for i in range(k):
-        pos = _positions(flat, i, m_bits).astype(jnp.int32)
-        # Invalid rows scatter out of range and are dropped.
-        pos = jnp.where(v, pos, m_bits)
-        bits = bits.at[pos].set(True, mode="drop")
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(
-        jnp.where(bits.reshape(m_bits // 32, 32),
-                  jnp.uint32(1) << shifts[None, :], jnp.uint32(0)),
-        axis=1, dtype=jnp.uint32)
-
-
 @functools.partial(jax.jit, static_argnames=("key", "mesh", "m_bits", "k"))
 def dist_bloom_build(table: Table, key: str, mesh: Mesh, *, m_bits: int,
                      k: int) -> jax.Array:
-    """Distributed bloom build: per-device partial filters OR-merged
-    across the mesh, then held replicated on every device.
+    """Distributed bloom build: per-device partial filters (the
+    ``bloom_build`` kernel over each device's partition) OR-merged across
+    the mesh, then held replicated on every device.
 
     Returns the merged (m_bits/32,) uint32 array — bit-identical to the
     global-view ``bloom_build`` over the concatenated column, because OR
@@ -298,15 +269,17 @@ def dist_bloom_build(table: Table, key: str, mesh: Mesh, *, m_bits: int,
     p = mesh.shape[AXIS]
 
     def f(col, valid):
-        part = _partial_bloom_words(col[0], valid[0], m_bits, k)
+        part = kops.bloom_build(col[0], valid[0], m_bits=m_bits, k=k)
         parts = jax.lax.all_gather(part, AXIS)        # (p, m_words)
         merged = parts[0]
         for i in range(1, p):
             merged = merged | parts[i]
         return merged[None]
 
-    words = _shard_map(
+    # check_vma=False: a Pallas kernel's outputs carry no varying-axes type.
+    words = jax.shard_map(
         f, mesh=mesh, in_specs=(P(AXIS), P(AXIS)), out_specs=P(AXIS),
+        check_vma=False,
     )(table.column(key), table.valid)
     # Every device holds the identical merged filter; take one replica.
     return words[0]
@@ -315,7 +288,8 @@ def dist_bloom_build(table: Table, key: str, mesh: Mesh, *, m_bits: int,
 @functools.partial(jax.jit, static_argnames=("key", "mesh"))
 def dist_zone_map_build(table: Table, key: str, mesh: Mesh) -> jax.Array:
     """Distributed zone-map build: per-device (min, max) partial intervals
-    merged across the mesh with an elementwise min/max reduce.
+    (the ``key_range`` kernel over each device's partition) merged across
+    the mesh with an elementwise min/max reduce.
 
     Returns the merged int32 ``(2,)`` interval — value-identical to the
     global-view ``kernels.zone_map.key_range`` over the concatenated
@@ -329,16 +303,13 @@ def dist_zone_map_build(table: Table, key: str, mesh: Mesh) -> jax.Array:
     """
 
     def f(col, valid):
-        flat = col[0].reshape(-1).astype(jnp.int32)
-        v = valid[0].reshape(-1)
-        part = jnp.stack([
-            jnp.min(jnp.where(v, flat, jnp.int32(_LO_IDENT))),
-            jnp.max(jnp.where(v, flat, jnp.int32(_HI_IDENT)))])
+        part = kops.key_range(col[0], valid[0])
         parts = jax.lax.all_gather(part, AXIS)        # (p, 2)
-        return merge_ranges(parts)[None]
+        return kops.merge_ranges(parts)[None]
 
-    out = _shard_map(
+    out = jax.shard_map(
         f, mesh=mesh, in_specs=(P(AXIS), P(AXIS)), out_specs=P(AXIS),
+        check_vma=False,
     )(table.column(key), table.valid)
     # Every device holds the identical merged interval; take one replica.
     return out[0]
@@ -372,7 +343,7 @@ def dist_key_set_build(table: Table, key: str, mesh: Mesh
         merged, n = key_set(gathered.reshape(-1), live.reshape(-1))
         return merged[None], n[None]
 
-    keys, n = _shard_map(
+    keys, n = jax.shard_map(
         f, mesh=mesh, in_specs=(P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS)),
     )(table.column(key), table.valid)
@@ -392,7 +363,7 @@ def dist_broadcast_hash_join(a: Table, b: Table, a_key: str, b_key: str,
         out_cols, out_valid = _attach(a_cols, a_valid[0], fb_cols, res)
         return ({n: c[None] for n, c in out_cols.items()}, out_valid[None])
 
-    cols, valid = _shard_map(
+    cols, valid = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS)),

@@ -17,13 +17,13 @@ is the skew signal: under Zipf keys it, not the mean, bounds wall-clock.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from ..kernels.partition_hist import partition_hist
-from .slots import (SHUFFLE_SEED, gather_rows, hash32, pair_capacity,
-                    slot_scatter)
+from ..kernels import ops as kops
+from .slots import SHUFFLE_SEED, hash32, pair_capacity, slot_scatter
 from .table import Table, concat_partitions
 
 #: Decorrelated from SHUFFLE_SEED/BUCKET_SEED: salt hashing must not undo
@@ -51,6 +51,7 @@ class ExchangeReport:
     straggler_bytes: float = 0.0  # bytes landing on the hottest partition
 
 
+@functools.partial(jax.jit, static_argnames=("p",))
 def _dest_partition(key: jax.Array, p: int) -> jax.Array:
     return (hash32(key, SHUFFLE_SEED) % jnp.uint32(p)).astype(jnp.int32)
 
@@ -104,32 +105,49 @@ def _exchange_by_dest(table: Table, dest: jax.Array, pair_cap: int,
     bincount of the destination ids).
     """
     p = table.num_partitions
-    scat = jax.vmap(lambda d, v: slot_scatter(d, v, p, pair_cap))(
-        dest, table.valid)  # idx: (p_src, p_dst, pair_cap)
-
-    send_cols, send_valid = jax.vmap(gather_rows)(table.columns, scat.idx)
-    # all_to_all == axis transpose in the global view.
-    recv_cols = {n: jnp.swapaxes(c, 0, 1).reshape(p, p * pair_cap)
-                 for n, c in send_cols.items()}
-    recv_valid = jnp.swapaxes(send_valid, 0, 1).reshape(p, p * pair_cap)
+    idx, recv_valid, overflow, moved, stayed = _route(table.valid, dest,
+                                                      pair_cap)
+    recv_cols = {n: _all_to_all(c, idx) for n, c in table.columns.items()}
     out = Table(recv_cols, recv_valid, partitioned_by=partitioned_by)
-
-    # Measured workload: rows that actually crossed partitions, plus the
-    # per-destination load histogram for straggler accounting.
-    src_ids = jnp.arange(p, dtype=jnp.int32)[:, None]
-    moved = jnp.sum(table.valid & (dest != src_ids))
-    stayed = jnp.sum(table.valid & (dest == src_ids))
-    loads = partition_hist(
-        jnp.where(table.valid, dest, -1).reshape(-1), nd=p)
+    loads = kops.hist(jnp.where(table.valid, dest, -1).reshape(-1), nd=p)
     rb = table.row_bytes
     report = ExchangeReport(
         kind,
         network_bytes=float(moved) * rb,
         local_bytes=float(stayed) * rb,
-        overflow_rows=int(jnp.sum(scat.overflow)),
+        overflow_rows=int(overflow),
         straggler_bytes=float(jnp.max(loads)) * rb,
     )
     return out, report
+
+
+@functools.partial(jax.jit, static_argnames=("pair_cap",))
+def _route(valid: jax.Array, dest: jax.Array, pair_cap: int):
+    """Slot each partition's rows by destination. Returns the
+    (p_src, p_dst, pair_cap) source-row slots, the received validity, the
+    overflowed row count, and the measured workload: rows that crossed
+    partitions and rows that stayed (the hottest destination's load comes
+    from the ``partition_hist`` bincount of the destination ids). Depends
+    on no column, so one compilation serves every schema of a shape."""
+    p = valid.shape[0]
+    scat = jax.vmap(lambda d, v: slot_scatter(d, v, p, pair_cap))(
+        dest, valid)
+    recv_valid = jnp.swapaxes(scat.idx >= 0, 0, 1).reshape(p, p * pair_cap)
+    src_ids = jnp.arange(p, dtype=jnp.int32)[:, None]
+    moved = jnp.sum(valid & (dest != src_ids))
+    stayed = jnp.sum(valid & (dest == src_ids))
+    return scat.idx, recv_valid, jnp.sum(scat.overflow), moved, stayed
+
+
+@jax.jit
+def _all_to_all(column: jax.Array, idx: jax.Array) -> jax.Array:
+    """One column through the routed slots: gather each source partition's
+    slot rows, then the all_to_all, which is an axis transpose in the
+    global view: (p_src, p_dst, cap) -> (p_dst, p_src * cap)."""
+    p, _, cap = idx.shape
+    sent = jax.vmap(lambda c, i: jnp.take(c, jnp.maximum(i, 0), axis=0))(
+        column, idx)
+    return jnp.swapaxes(sent, 0, 1).reshape(p, p * cap)
 
 
 def shuffle(table: Table, key: str, capacity_factor: float = 2.0
@@ -236,8 +254,7 @@ def hot_fine_buckets(table: Table, key: str, nf: int, p: int,
     fine-bucket ids (so the caller need not re-hash the hot table).
     """
     fine = _fine_bucket(table.column(key), nf)
-    counts = partition_hist(jnp.where(table.valid, fine, -1).reshape(-1),
-                            nd=nf)
+    counts = kops.hist(jnp.where(table.valid, fine, -1).reshape(-1), nd=nf)
     threshold = hot_share * jnp.sum(counts) / p
     return counts > threshold, fine
 
@@ -311,7 +328,7 @@ def key_skew(table: Table, key: str, p: int | None = None,
     """
     p = p or table.num_partitions
     dest = jnp.where(table.valid, _dest_partition(table.column(key), p), -1)
-    counts = partition_hist(dest.reshape(-1), nd=p)
+    counts = kops.hist(dest.reshape(-1), nd=p)
     total = int(jnp.sum(counts))
     if total == 0:
         return 1.0

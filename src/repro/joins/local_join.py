@@ -8,27 +8,35 @@ through ``match_idx`` afterwards.
 TPU adaptation (DESIGN.md §2): the *hash* join is a radix hash join —
 bucket both sides by a multiplicative hash, then run a dense tiled key-match
 within each bucket (the ``tiled_probe`` Pallas kernel is the in-VMEM
-primitive; a jnp path with identical semantics is the CPU default). The
-*sort* join sorts both sides (bitonic tile kernel / XLA sort) and merges via
-binary search. The *nested loop* compares all pairs with an arbitrary
-predicate.
+primitive, taken with ``use_kernel=True``). A jnp path with identical
+semantics is the default on every backend, TPU included, until a benchmark
+has measured the two paths against each other. The *sort* join sorts the
+build side (XLA sort by default, the bitonic tile kernel with
+``use_kernel_sort=True``) and merges via binary search. The *nested loop*
+compares all pairs with an arbitrary predicate.
 
 Invalid-row sentinels: probe side -1, build side -2 (never equal).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from ..kernels import ops as kops
-from ..kernels import ref as kref
 from .slots import BUCKET_SEED, hash32, slot_scatter
 
 A_SENTINEL = -1
 B_SENTINEL = -2
+
+#: Candidate cells (probe rows x bucket slots) the jnp probe gathers at
+#: once. Larger probe sides go through in row chunks, so the candidate
+#: tiles stay at 64 MiB of int32 per partition however many rows a shuffle
+#: lands on it.
+PROBE_TILE = 1 << 24
 
 
 class LocalJoinResult(NamedTuple):
@@ -48,6 +56,33 @@ def _bucket_of(keys: jax.Array, nb: int) -> jax.Array:
     return (hash32(keys, BUCKET_SEED) % jnp.uint32(nb)).astype(jnp.int32)
 
 
+def _probe_buckets(ak: jax.Array, ab: jax.Array, bk_bucketed: jax.Array,
+                   b_rows: jax.Array, chunk: int) -> jax.Array:
+    """B row of each probe key's first match in its bucket, -1 if none.
+    Per ``chunk``-row slice of the probe side: gather the bucket's keys and
+    row ids, compare, take the first hit."""
+    n = ak.shape[0]
+    n_chunks = -(-n // chunk)
+    pad = n_chunks * chunk - n
+    ak = jnp.pad(ak, (0, pad), constant_values=A_SENTINEL)
+    ab = jnp.pad(ab, (0, pad))
+
+    def one(x):
+        keys, buckets = x
+        cand_keys = jnp.take(bk_bucketed, buckets, axis=0)  # (chunk, cap_b)
+        cand_rows = jnp.take(b_rows, buckets, axis=0)       # (chunk, cap_b)
+        hit = cand_keys == keys[:, None]
+        slot = jnp.argmax(hit, axis=1)
+        idx = jnp.take_along_axis(cand_rows, slot[:, None], axis=1)[:, 0]
+        return jnp.where(jnp.any(hit, axis=1), idx, -1)
+
+    idx = jax.lax.map(one, (ak.reshape(n_chunks, chunk),
+                            ab.reshape(n_chunks, chunk)))
+    return idx.reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("n_buckets", "bucket_cap_factor",
+                                             "use_kernel"))
 def hash_join(a_keys: jax.Array, a_valid: jax.Array,
               b_keys: jax.Array, b_valid: jax.Array,
               *, n_buckets: int | None = None,
@@ -59,8 +94,8 @@ def hash_join(a_keys: jax.Array, a_valid: jax.Array,
     (C'_build ~ |B|). Probe: each A row inspects only its bucket's keys
     (C_probe ~ |A| + fanout*|B|). With ``use_kernel`` both sides are
     bucketed and each bucket pair runs the dense ``tiled_probe`` Pallas
-    match (the TPU execution plan); the default jnp path gathers each probe
-    row's bucket tile and compares — identical semantics, fast on CPU.
+    match; the default jnp path, on every backend, gathers each probe row's
+    bucket tile and compares — identical semantics.
     """
     na, b_cap = a_keys.shape[0], b_keys.shape[0]
     ak = _sanitize(a_keys, a_valid, A_SENTINEL)
@@ -80,13 +115,9 @@ def hash_join(a_keys: jax.Array, a_valid: jax.Array,
 
     if not use_kernel:
         # Probe: gather each A row's bucket tile and match within it.
-        cand_keys = jnp.take(bk_bucketed, ab, axis=0)      # (na, cap_b)
-        cand_rows = jnp.take(scat_b.idx, ab, axis=0)       # (na, cap_b)
-        hit = cand_keys == ak[:, None]
-        slot = jnp.argmax(hit, axis=1)
-        found = jnp.any(hit, axis=1)
-        idx = jnp.take_along_axis(cand_rows, slot[:, None], axis=1)[:, 0]
-        found = found & (idx >= 0) & a_valid
+        chunk = min(max(8, PROBE_TILE // b_slot_cap), max(na, 1))
+        idx = _probe_buckets(ak, ab, bk_bucketed, scat_b.idx, chunk)
+        found = (idx >= 0) & a_valid
         return LocalJoinResult(jnp.where(found, idx, -1).astype(jnp.int32),
                                found)
 
@@ -96,8 +127,7 @@ def hash_join(a_keys: jax.Array, a_valid: jax.Array,
     ak_bucketed = jnp.where(scat_a.idx >= 0,
                             jnp.take(ak, jnp.maximum(scat_a.idx, 0)),
                             A_SENTINEL)  # (nb, cap_a)
-    slot_in_bucket = jax.vmap(
-        lambda aks, bks: kops.probe(aks, bks))(ak_bucketed, bk_bucketed)
+    slot_in_bucket = kops.probe(ak_bucketed, bk_bucketed)  # one per bucket
     # Resolve to B row ids and scatter back to A's original row order.
     b_rows = jnp.take_along_axis(
         scat_b.idx, jnp.maximum(slot_in_bucket, 0), axis=1)
@@ -113,6 +143,7 @@ def hash_join(a_keys: jax.Array, a_valid: jax.Array,
 # Sort join (sort both sides, merge by binary search).
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames=("use_kernel_sort",))
 def sort_join(a_keys: jax.Array, a_valid: jax.Array,
               b_keys: jax.Array, b_valid: jax.Array,
               *, use_kernel_sort: bool = False) -> LocalJoinResult:
@@ -131,7 +162,8 @@ def sort_join(a_keys: jax.Array, a_valid: jax.Array,
     if use_kernel_sort:
         bk_sorted, b_perm = kops.sort_pairs(bk, rows_b)
     else:
-        bk_sorted, b_perm = kref.bitonic_sort_ref(bk, rows_b)
+        b_perm = jnp.argsort(bk, stable=True).astype(jnp.int32)
+        bk_sorted = bk[b_perm]
 
     # Sort A as the method prescribes (workload accounting); the merge below
     # is order-insensitive so correctness is unaffected.

@@ -313,8 +313,7 @@ def hypercube_multiway_join(tables: list, spec: HypercubeSpec,
         # unique so first-match is exact).
         l1, l2 = spec.links
         b1, b2 = shards[l1.build], shards[l2.build]
-        idx1, idx2 = jax.vmap(
-            lambda a1, a2, bk, ck: kops.probe3(a1, a2, bk, ck))(
+        idx1, idx2 = kops.probe3(  # one probe per partition
             jnp.where(valid, cols[l1.probe_col], A_SENTINEL).astype(jnp.int32),
             jnp.where(valid, cols[l2.probe_col], A_SENTINEL).astype(jnp.int32),
             _sanitized(b1, l1.build_col, B_SENTINEL),

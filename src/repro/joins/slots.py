@@ -12,6 +12,7 @@ shard_map (distributed executor) unchanged.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -49,6 +50,7 @@ class SlotScatter(NamedTuple):
     overflow: jax.Array  # () int32 number of dropped valid rows
 
 
+@functools.partial(jax.jit, static_argnames=("nd", "cap"))
 def slot_scatter(dest: jax.Array, valid: jax.Array, nd: int, cap: int
                  ) -> SlotScatter:
     """Group rows by destination into fixed slots.

@@ -12,6 +12,7 @@ runtime statistic; ``measure()`` produces it after every exchange.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict
 
 import jax
@@ -149,16 +150,26 @@ def compact_partitions(table: Table, capacity: int | None = None,
     """
     if not table.stacked:
         raise ValueError("compact expects a stacked table")
-    counts = jnp.sum(table.valid, axis=1)
-    need = int(jnp.max(counts))
+    need = int(_max_live(table.valid))
     cap = capacity or max(8, 1 << (max(int(need * slack), 1) - 1).bit_length())
     cap = min(cap, table.capacity)
-
-    order = jnp.argsort(~table.valid, axis=1, stable=True)[:, :cap]
+    order = _front_order(table.valid, cap)
     cols = {n: jnp.take_along_axis(c, order, axis=1)
             for n, c in table.columns.items()}
     valid = jnp.take_along_axis(table.valid, order, axis=1)
     return Table(cols, valid, table.partitioned_by)
+
+
+@jax.jit
+def _max_live(valid: jax.Array) -> jax.Array:
+    return jnp.max(jnp.sum(valid, axis=1))
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))
+def _front_order(valid: jax.Array, cap: int) -> jax.Array:
+    """Per partition, the first ``cap`` row indices with valid rows first
+    (stable)."""
+    return jnp.argsort(~valid, axis=1, stable=True)[:, :cap]
 
 
 def concat_partitions(table: Table) -> Table:

@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On the CPU CI container the kernels run in interpret mode (the kernel body
-executes in Python, validating the exact TPU program); on a TPU backend they
-compile natively. Callers use these wrappers, never pallas_call directly.
+On a TPU backend the kernels compile natively; on any other backend (the
+CPU CI container) they run in interpret mode (the kernel body executes in
+Python, validating the exact TPU program). The backend decides, never a
+caller: code outside ``repro.kernels`` uses these wrappers, and the kernel
+functions themselves take a required ``interpret`` flag.
 """
 
 from __future__ import annotations
@@ -12,9 +14,25 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import bloom as _bloom
+from . import zone_map as _zone_map
 from .bitonic_sort import MAX_TILE, bitonic_sort_tile
 from .partition_hist import partition_hist
 from .tiled_probe import tiled_probe, tiled_probe3
+
+# Kernel-free halves of the zone-map filter (plain XLA compares/reduces).
+merge_ranges = _zone_map.merge_ranges
+range_probe = _zone_map.range_probe
+
+#: ``jax.monitoring`` event recorded per kernel call from Python (one per
+#: eager call, one per trace under jit): ``EVENT_PREFIX + kernel name``.
+#: A listener can count which kernels a run reached; without one the
+#: record is a no-op.
+EVENT_PREFIX = "/repro/kernels/"
+
+
+def _called(kernel: str) -> None:
+    jax.monitoring.record_event(EVENT_PREFIX + kernel)
 
 
 @functools.cache
@@ -22,23 +40,45 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def probe(a_keys: jax.Array, b_keys: jax.Array, *, ta: int = 256,
-          tb: int = 512) -> jax.Array:
-    """First-match index of each probe key in the build keys (-1 if none)."""
-    return tiled_probe(a_keys, b_keys, ta=ta, tb=tb, interpret=_interpret())
+def probe(a_keys: jax.Array, b_keys: jax.Array) -> jax.Array:
+    """First-match index of each probe key in the build keys (-1 if none),
+    per row of any leading batch dimensions."""
+    _called("tiled_probe")
+    return tiled_probe(a_keys, b_keys, interpret=_interpret())
 
 
 def probe3(a1_keys: jax.Array, a2_keys: jax.Array, b_keys: jax.Array,
-           c_keys: jax.Array, *, ta: int = 256, tb: int = 512
-           ) -> tuple[jax.Array, jax.Array]:
+           c_keys: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Fused two-build first-match probe (hypercube 3-way local join)."""
-    return tiled_probe3(a1_keys, a2_keys, b_keys, c_keys, ta=ta, tb=tb,
+    _called("tiled_probe3")
+    return tiled_probe3(a1_keys, a2_keys, b_keys, c_keys,
                         interpret=_interpret())
 
 
-def hist(dest: jax.Array, nd: int, *, tn: int = 1024) -> jax.Array:
+def hist(dest: jax.Array, nd: int) -> jax.Array:
     """Partition-destination histogram (skew/capacity statistics)."""
-    return partition_hist(dest, nd=nd, tn=tn, interpret=_interpret())
+    _called("partition_hist")
+    return partition_hist(dest, nd=nd, interpret=_interpret())
+
+
+def bloom_build(keys: jax.Array, valid: jax.Array | None = None, *,
+                m_bits: int, k: int) -> jax.Array:
+    """Bit-packed (m_bits/32,) uint32 bloom filter of the valid keys."""
+    _called("bloom_build")
+    return _bloom.bloom_build(keys, valid, m_bits=m_bits, k=k,
+                              interpret=_interpret())
+
+
+def bloom_probe(keys: jax.Array, bits: jax.Array, *, k: int) -> jax.Array:
+    """Keep-mask of ``keys`` against a ``bloom_build`` filter."""
+    _called("bloom_probe")
+    return _bloom.bloom_probe(keys, bits, k=k, interpret=_interpret())
+
+
+def key_range(keys: jax.Array, valid: jax.Array | None = None) -> jax.Array:
+    """(min, max) of the valid keys: the zone-map build."""
+    _called("key_range")
+    return _zone_map.key_range(keys, valid, interpret=_interpret())
 
 
 def sort_pairs(keys: jax.Array, values: jax.Array):
@@ -50,6 +90,7 @@ def sort_pairs(keys: jax.Array, values: jax.Array):
     """
     n = keys.shape[0]
     if n and not (n & (n - 1)) and n <= MAX_TILE:
+        _called("bitonic_sort_tile")
         return bitonic_sort_tile(keys, values, interpret=_interpret())
     order = jnp.argsort(keys)
     return keys[order], values[order]

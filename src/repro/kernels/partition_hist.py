@@ -34,8 +34,8 @@ def _hist_kernel(dest_ref, out_ref, *, nd: int):
 
 
 @functools.partial(jax.jit, static_argnames=("nd", "tn", "interpret"))
-def partition_hist(dest: jax.Array, *, nd: int, tn: int = DEFAULT_TN,
-                   interpret: bool = True) -> jax.Array:
+def partition_hist(dest: jax.Array, *, nd: int, interpret: bool,
+                   tn: int = DEFAULT_TN) -> jax.Array:
     """counts[k] = #{i : dest[i] == k}; dest < 0 rows are not counted."""
     if dest.dtype != jnp.int32:
         raise TypeError("partition_hist expects int32 destinations")

@@ -13,10 +13,12 @@ positives, at a fraction of a bloom filter's broadcast cost.
 ``key_range`` is the build reduce: a tiled Pallas kernel in the same shape
 as ``partition_hist`` — grid over key tiles, accumulating elementwise
 min/max into a tiny (1, 2) output block that stays resident across the
-grid. Invalid rows are masked to the identity elements (+INT_MAX for min,
--INT_MAX-ish for max), so an empty or all-invalid build yields the empty
-interval (lo > hi) whose probe mask rejects every row — the same
-degenerate-build contract as the zero bloom filter.
+grid. The block lives in SMEM, the TPU's scalar memory: each tile reduces
+to two scalars, and VMEM takes only vector stores. Invalid rows are masked
+to the identity elements (+INT_MAX for min, -INT_MAX-ish for max), so an
+empty or all-invalid build yields the empty interval (lo > hi) whose probe
+mask rejects every row — the same degenerate-build contract as the zero
+bloom filter.
 
 ``range_probe`` needs no kernel: the keep mask is two vectorized compares
 fused into the caller by XLA.
@@ -29,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TN = 1024
 
@@ -56,7 +59,7 @@ def _minmax_kernel(keys_ref, valid_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def key_range(keys: jax.Array, valid: jax.Array | None = None, *,
-              tn: int = DEFAULT_TN, interpret: bool = True) -> jax.Array:
+              interpret: bool, tn: int = DEFAULT_TN) -> jax.Array:
     """(min, max) of the valid entries of ``keys`` as an int32 (2,) array.
 
     Any input shape / integer dtype (viewed as int32, like the bloom pair).
@@ -77,7 +80,8 @@ def key_range(keys: jax.Array, valid: jax.Array | None = None, *,
         grid=(flat.shape[0] // tn,),
         in_specs=[pl.BlockSpec((tn,), lambda i: (i,)),
                   pl.BlockSpec((tn,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((1, 2), lambda i: (0, 0),
+                               memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 2), jnp.int32),
         interpret=interpret,
     )(flat, v)
@@ -100,15 +104,3 @@ def range_probe(keys: jax.Array, lo_hi: jax.Array) -> jax.Array:
     negatives ever: every build key lies inside its own min/max)."""
     k = keys.astype(jnp.int32)
     return (k >= lo_hi[0]) & (k <= lo_hi[1])
-
-
-def key_range_ref(keys, valid=None):
-    """Pure-numpy reference of ``key_range`` (test oracle)."""
-    import numpy as np
-    flat = np.asarray(keys, dtype=np.int32).reshape(-1)
-    v = (np.ones(flat.shape, bool) if valid is None
-         else np.asarray(valid, bool).reshape(-1))
-    live = flat[v]
-    if live.size == 0:
-        return np.array([_LO_IDENT, _HI_IDENT], np.int32)
-    return np.array([live.min(), live.max()], np.int32)

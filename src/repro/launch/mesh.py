@@ -10,19 +10,12 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """axis_types only exists on newer jax; older versions are Auto-only
-    anyway, so omitting the kwarg is semantically identical."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_axes(mesh) -> tuple:
@@ -33,4 +26,4 @@ def mesh_axes(mesh) -> tuple:
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over host devices (tests, examples)."""
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

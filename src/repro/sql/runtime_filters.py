@@ -59,8 +59,7 @@ from ..core.cost_model import (CostParams, SEMI_JOIN_BITS_PER_KEY,
 from ..core.psts import key_set, semi_join_mask
 from ..core.stats import StatsSource, TableStats
 from ..joins.table import Table
-from ..kernels.bloom import bloom_build, bloom_probe
-from ..kernels.zone_map import key_range, range_probe
+from ..kernels import ops as kops
 from .datagen import catalog_fingerprint
 from .logical import (Node, Project, RuntimeFilter, Scan, filter_chain)
 
@@ -114,11 +113,11 @@ class BloomKind(RuntimeFilterKind):
                            bloom_total_cost(m_bits, params))
 
     def build(self, build, key, rf):
-        return bloom_build(build.column(key), build.valid,
-                           m_bits=rf.m_bits, k=rf.k)
+        return kops.bloom_build(build.column(key), build.valid,
+                                m_bits=rf.m_bits, k=rf.k)
 
     def probe(self, keys, payload, rf):
-        return bloom_probe(keys, payload, k=rf.k)
+        return kops.bloom_probe(keys, payload, k=rf.k)
 
 
 class ZoneMapKind(RuntimeFilterKind):
@@ -137,10 +136,10 @@ class ZoneMapKind(RuntimeFilterKind):
                            zone_map_cost(params))
 
     def build(self, build, key, rf):
-        return key_range(build.column(key), build.valid)
+        return kops.key_range(build.column(key), build.valid)
 
     def probe(self, keys, payload, rf):
-        return range_probe(keys, payload)
+        return kops.range_probe(keys, payload)
 
 
 class SemiJoinKind(RuntimeFilterKind):

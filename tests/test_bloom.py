@@ -21,7 +21,8 @@ from helpers.hypothesis_compat import given, settings
 from helpers.hypothesis_compat import strategies as st
 
 from repro.core.cost_model import bloom_fpr, bloom_params
-from repro.kernels.bloom import bloom_build, bloom_build_ref, bloom_probe
+from repro.kernels.ops import bloom_build, bloom_probe
+from repro.kernels.ref import bloom_build_ref, bloom_probe_ref
 
 #: Integer dtypes a key column may arrive in (kernels view them as int32).
 KEY_DTYPES = (np.int32, np.uint32, np.int16, np.int8)
@@ -134,3 +135,38 @@ def test_stacked_shape_roundtrip():
     mask = bloom_probe(keys, bits, k=k)
     assert mask.shape == keys.shape
     assert bool(np.asarray(mask).all())
+
+
+@settings(max_examples=5, deadline=None)
+@given(n=st.integers(1, 600), probes=st.integers(1, 3000),
+       seed=st.integers(0, 10_000))
+def test_probe_matches_numpy_reference(n, probes, seed):
+    """The probe kernel's mask equals the numpy bit test, hits and false
+    positives alike."""
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(-1000, 1000, n).astype(np.int32)
+    m, k = bloom_params(n)
+    bits = np.asarray(bloom_build(keys, m_bits=m, k=k))
+    q = rng.integers(-2000, 2000, probes).astype(np.int32)
+    got = np.asarray(bloom_probe(q, bits, k=k))
+    assert np.array_equal(got, bloom_probe_ref(q, bits, k=k))
+
+
+@pytest.mark.parametrize("n", [1, 3000])
+def test_multi_tile_filter_matches_numpy_reference(n):
+    """A 131072-bit filter (the 12k-row customer dimension's) spans four
+    bitmap row tiles: the build ORs each tile over every key tile and the
+    probe counts a key's bits across the row tiles."""
+    from repro.kernels.bloom import TR
+    m, k = 131_072, 8
+    assert m // 128 == 4 * TR
+    rng = np.random.default_rng(n)
+    keys = rng.integers(-(1 << 30), 1 << 30, n).astype(np.int32)
+    valid = rng.random(n) < 0.8
+    bits = np.asarray(bloom_build(keys, valid, m_bits=m, k=k))
+    assert np.array_equal(bits, bloom_build_ref(keys, valid, m_bits=m, k=k))
+    q = np.concatenate([keys, rng.integers(-(1 << 30), 1 << 30, 5000)
+                        .astype(np.int32)])
+    got = np.asarray(bloom_probe(q, bits, k=k))
+    assert np.array_equal(got, bloom_probe_ref(q, bits, k=k))
+    assert got[:n][valid].all()

@@ -37,7 +37,7 @@ from repro.joins import from_numpy, partition_round_robin, run_equi_join
 from repro.joins.methods import (HypercubeLink, HypercubeSpec,
                                  hypercube_multiway_join)
 from repro.joins.ref import ref_equi_join, ref_multiway_join, rows_as_set
-from repro.kernels.bloom import bloom_build, bloom_probe
+from repro.kernels.ops import bloom_build, bloom_probe
 from repro.sql.datagen import _zipf_fks
 
 ALL_METHODS = [JoinMethod.BROADCAST_HASH, JoinMethod.SHUFFLE_HASH,

@@ -19,8 +19,8 @@ from repro.joins import from_numpy, partition_round_robin
 from repro.joins.distributed import (dist_bloom_build, dist_key_set_build,
                                      dist_zone_map_build, make_join_mesh,
                                      place)
-from repro.kernels.bloom import bloom_build, bloom_build_ref
-from repro.kernels.zone_map import key_range_ref
+from repro.kernels.ops import bloom_build
+from repro.kernels.ref import bloom_build_ref, key_range_ref
 
 
 def _stacked(p, n=1000, seed=3, hole_frac=0.2):

@@ -24,7 +24,8 @@ from repro.core.cost_model import (CostParams, ZONE_MAP_BITS,
                                    zone_map_cost)
 from repro.core.psts import distinct_count, key_set, semi_join_mask
 from repro.joins.ref import rows_as_set, rows_close
-from repro.kernels.zone_map import key_range, key_range_ref, range_probe
+from repro.kernels.ops import key_range, range_probe
+from repro.kernels.ref import key_range_ref
 from repro.sql import (Executor, FilterCache, FilteredStrategy,
                        RelJoinStrategy, filter_cache_key, filtered_queries,
                        generate, plan_runtime_filters)

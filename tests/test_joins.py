@@ -153,6 +153,44 @@ def test_local_joins_agree(seed, na, nb):
                                   np.asarray(s.match_idx))
 
 
+@pytest.mark.parametrize("na", [3001, 400_000])
+def test_hash_join_chunked_probe_matches_oracle(na):
+    """The probe side goes through in row chunks of PROBE_TILE / slots
+    candidate cells: 400k rows against 49-slot buckets take two chunks,
+    the last one ragged. The matches are the sort join's."""
+    import repro.joins.local_join as lj
+    rng = np.random.default_rng(na)
+    nb = 97                    # 8 buckets of 49 slots
+    assert (na > lj.PROBE_TILE // 49) == (na == 400_000)
+    ak = jnp.asarray(rng.integers(0, nb * 2, na), jnp.int32)
+    av = jnp.asarray(rng.uniform(size=na) < 0.9)
+    bk = jnp.asarray(rng.permutation(nb * 2)[:nb], jnp.int32)
+    bv = jnp.asarray(rng.uniform(size=nb) < 0.9)
+    got = hash_join(ak, av, bk, bv)
+    want = sort_join(ak, av, bk, bv)
+    np.testing.assert_array_equal(np.asarray(got.match_idx),
+                                  np.asarray(want.match_idx))
+    np.testing.assert_array_equal(np.asarray(got.found),
+                                  np.asarray(want.found))
+
+
+@pytest.mark.parametrize("nb", [128, 1024])
+def test_sort_join_kernel_sort_matches(nb):
+    """use_kernel_sort routes a power-of-two build tile through the bitonic
+    kernel; the matches equal the default XLA-sort path."""
+    rng = np.random.default_rng(nb)
+    ak = jnp.asarray(rng.integers(0, nb * 2, 700), jnp.int32)
+    av = jnp.asarray(rng.uniform(size=700) < 0.9)
+    bk = jnp.asarray(rng.permutation(nb * 2)[:nb], jnp.int32)
+    bv = jnp.asarray(rng.uniform(size=nb) < 0.9)
+    want = sort_join(ak, av, bk, bv)
+    got = sort_join(ak, av, bk, bv, use_kernel_sort=True)
+    np.testing.assert_array_equal(np.asarray(got.match_idx),
+                                  np.asarray(want.match_idx))
+    np.testing.assert_array_equal(np.asarray(got.found),
+                                  np.asarray(want.found))
+
+
 @pytest.mark.slow
 def test_distributed_shard_map_executor():
     """Real collectives on 8 placeholder devices (subprocess so the main

@@ -36,6 +36,32 @@ def test_probe_tile_sweep(ta, tb):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("batch", [(3,), (11,), (2, 9)])
+def test_probe_batched_rows(batch):
+    """One independent probe per batch row: rows beyond a whole sublane
+    block and probe/build lengths off the 128-lane tiles."""
+    rng = np.random.default_rng(len(batch) * 100 + batch[-1])
+    a = rng.integers(-2, 60, batch + (300,)).astype(np.int32)
+    b = rng.integers(-2, 60, batch + (129,)).astype(np.int32)
+    got = np.asarray(ops.probe(jnp.asarray(a), jnp.asarray(b)))
+    want = [np.asarray(ref.tiled_probe_ref(jnp.asarray(x), jnp.asarray(y)))
+            for x, y in zip(a.reshape(-1, 300), b.reshape(-1, 129))]
+    np.testing.assert_array_equal(got, np.stack(want).reshape(got.shape))
+
+
+def test_probe3_matches_two_probes():
+    """The fused 3-way probe equals two separate probes, builds of unequal
+    lengths included."""
+    rng = np.random.default_rng(3)
+    a1, a2 = (jnp.asarray(rng.integers(-1, 80, (11, 300)), jnp.int32)
+              for _ in range(2))
+    b = jnp.asarray(rng.integers(0, 80, (11, 100)), jnp.int32)
+    c = jnp.asarray(rng.integers(0, 80, (11, 250)), jnp.int32)
+    g1, g2 = ops.probe3(a1, a2, b, c)
+    np.testing.assert_array_equal(np.asarray(g1), np.asarray(ops.probe(a1, b)))
+    np.testing.assert_array_equal(np.asarray(g2), np.asarray(ops.probe(a2, c)))
+
+
 def test_probe_first_match_semantics():
     a = jnp.asarray([5, 9, 5], jnp.int32)
     b = jnp.asarray([1, 5, 3, 5], jnp.int32)  # duplicate build keys
