@@ -1,0 +1,10 @@
+"""CPU tests of the benchmark harness; run them as
+``python -m pytest chipbench/tests`` from the repository root."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
