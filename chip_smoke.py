@@ -50,29 +50,31 @@ class Smoke:
     def __init__(self, device):
         import jax
 
-        from repro.kernels import ops as kops
         self.device = device
         self.failures: list[str] = []
-        #: Kernel calls from Python, from the events ``kernels.ops`` records.
-        self.calls: collections.Counter = collections.Counter()
         #: XLA backend compilations and their seconds.
         self.compiles = [0, 0.0]
-
-        def count(event: str, **_):
-            if event.startswith(kops.EVENT_PREFIX):
-                self.calls[event[len(kops.EVENT_PREFIX):]] += 1
 
         def compiled(event: str, duration: float, **_):
             if event == COMPILE_EVENT:
                 self.compiles[0] += 1
                 self.compiles[1] += duration
 
-        jax.monitoring.register_event_listener(count)
         jax.monitoring.register_event_duration_secs_listener(compiled)
+
+    @property
+    def calls(self) -> collections.Counter:
+        """Kernel calls from Python so far, from the engine's
+        ``kernel.<name>`` counters (``repro.obs``)."""
+        from repro import obs
+
+        return collections.Counter(
+            {name[len("kernel."):]: n for name, n in obs.snapshot().items()
+             if name.startswith("kernel.")})
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        before = collections.Counter(self.calls)
+        before = self.calls
         n0, s0 = self.compiles
         print(f"-- {name}", flush=True)
         t0 = time.perf_counter()
@@ -219,7 +221,7 @@ def one_chip(smoke: Smoke) -> None:
     print(f"   queries: {list(queries)}; from filtered_queries(): {filtered}")
 
     service = QueryService(catalog)
-    before = collections.Counter(smoke.calls)
+    before = smoke.calls
     with smoke.phase("QueryService batch (default strategy)"):
         subs = {n: service.submit(q, name=n) for n, q in queries.items()}
         results = {n: r for rep in service.run()
@@ -248,7 +250,7 @@ def one_chip(smoke: Smoke) -> None:
                     f"star_oracle: rows equal the numpy oracle "
                     f"({len(want)} rows)")
 
-    before = collections.Counter(smoke.calls)
+    before = smoke.calls
     strategy = dataclasses.replace(service.strategy, cache=FilterCache())
     with smoke.phase("Executor(use_kernel=True)"):
         for n in queries:

@@ -41,6 +41,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .cost_model import JoinMethod
 
 #: Sentinel used to pad the sorted key set to its static capacity. Chosen
@@ -83,7 +84,7 @@ def key_set(keys: jax.Array, valid: jax.Array | None = None
 def distinct_count(keys: jax.Array, valid: jax.Array | None = None) -> int:
     """Concrete number of distinct valid keys (host sync)."""
     _, n = key_set(keys, valid)
-    return int(n)
+    return int(obs.fetch(n))
 
 
 def semi_join_mask(probe_keys: jax.Array, sorted_keys: jax.Array,

@@ -15,6 +15,7 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .exchange import ExchangeReport, shuffle
 from .table import Table
 
@@ -85,12 +86,13 @@ def group_aggregate(table: Table, key: str,
     """Distributed group-by: shuffle by key + local segment aggregation."""
     if not table.stacked:
         raise ValueError("group_aggregate expects a stacked table")
-    shuffled, report = shuffle(table, key, capacity_factor)
-    order, seg, live, out_valid, group_key = _segments(
-        shuffled.column(key), shuffled.valid)
-    out_cols = {f"{op}_{col}": _agg_column(shuffled.column(col), order, seg,
-                                           live, op)
-                for col, op in aggs}
+    with obs.span("op.aggregate"):
+        shuffled, report = shuffle(table, key, capacity_factor)
+        order, seg, live, out_valid, group_key = _segments(
+            shuffled.column(key), shuffled.valid)
+        out_cols = {f"{op}_{col}": _agg_column(shuffled.column(col), order,
+                                               seg, live, op)
+                    for col, op in aggs}
     out_cols[key] = group_key
     # Output is hash-partitioned by the group key: downstream shuffles on
     # the same key are elided (§3.7 key-dependency).
@@ -105,15 +107,17 @@ def global_aggregate(table: Table, aggs: Sequence[Tuple[str, str]]
     for col, op in aggs:
         c = table.column(col)
         if op == "count":
-            out[f"count_{col}"] = float(jnp.sum(v))
+            out[f"count_{col}"] = float(obs.fetch(jnp.sum(v)))
         elif op == "sum":
-            out[f"sum_{col}"] = float(jnp.sum(jnp.where(v, c, 0)))
+            out[f"sum_{col}"] = float(obs.fetch(jnp.sum(jnp.where(v, c, 0))))
         elif op == "mean":
-            s = float(jnp.sum(jnp.where(v, c, 0)))
-            n = float(jnp.sum(v))
+            s = float(obs.fetch(jnp.sum(jnp.where(v, c, 0))))
+            n = float(obs.fetch(jnp.sum(v)))
             out[f"mean_{col}"] = s / max(n, 1.0)
         elif op == "min":
-            out[f"min_{col}"] = float(jnp.min(jnp.where(v, c, jnp.inf)))
+            out[f"min_{col}"] = float(obs.fetch(
+                jnp.min(jnp.where(v, c, jnp.inf))))
         elif op == "max":
-            out[f"max_{col}"] = float(jnp.max(jnp.where(v, c, -jnp.inf)))
+            out[f"max_{col}"] = float(obs.fetch(
+                jnp.max(jnp.where(v, c, -jnp.inf))))
     return out

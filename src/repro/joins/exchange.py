@@ -22,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels import ops as kops
 from .slots import SHUFFLE_SEED, hash32, pair_capacity, slot_scatter
 from .table import Table, concat_partitions
@@ -82,13 +83,15 @@ def broadcast(table: Table) -> tuple[Table, ExchangeReport]:
     if not table.stacked:
         raise ValueError("broadcast expects a stacked table")
     p = table.num_partitions
-    full = concat_partitions(table)
-    rows = full.count()
-    bytes_all = rows * full.row_bytes
-    report = ExchangeReport("broadcast",
-                            network_bytes=(p - 1) * bytes_all,
-                            local_bytes=bytes_all,
-                            straggler_bytes=float(bytes_all))
+    with obs.span("op.exchange"):
+        full = concat_partitions(table)
+        rows = full.count()
+        bytes_all = rows * full.row_bytes
+        report = ExchangeReport("broadcast",
+                                network_bytes=(p - 1) * bytes_all,
+                                local_bytes=bytes_all,
+                                straggler_bytes=float(bytes_all))
+        obs.count("exchange_bytes", report.network_bytes)
     return full, report
 
 
@@ -105,19 +108,23 @@ def _exchange_by_dest(table: Table, dest: jax.Array, pair_cap: int,
     bincount of the destination ids).
     """
     p = table.num_partitions
-    idx, recv_valid, overflow, moved, stayed = _route(table.valid, dest,
-                                                      pair_cap)
-    recv_cols = {n: _all_to_all(c, idx) for n, c in table.columns.items()}
-    out = Table(recv_cols, recv_valid, partitioned_by=partitioned_by)
-    loads = kops.hist(jnp.where(table.valid, dest, -1).reshape(-1), nd=p)
-    rb = table.row_bytes
-    report = ExchangeReport(
-        kind,
-        network_bytes=float(moved) * rb,
-        local_bytes=float(stayed) * rb,
-        overflow_rows=int(overflow),
-        straggler_bytes=float(jnp.max(loads)) * rb,
-    )
+    with obs.span("op.exchange"):
+        idx, recv_valid, overflow, moved, stayed = _route(table.valid, dest,
+                                                          pair_cap)
+        recv_cols = {n: _all_to_all(c, idx)
+                     for n, c in table.columns.items()}
+        out = Table(recv_cols, recv_valid, partitioned_by=partitioned_by)
+        loads = kops.hist(jnp.where(table.valid, dest, -1).reshape(-1),
+                          nd=p)
+        rb = table.row_bytes
+        report = ExchangeReport(
+            kind,
+            network_bytes=float(obs.fetch(moved)) * rb,
+            local_bytes=float(obs.fetch(stayed)) * rb,
+            overflow_rows=int(obs.fetch(overflow)),
+            straggler_bytes=float(obs.fetch(jnp.max(loads))) * rb,
+        )
+        obs.count("exchange_bytes", report.network_bytes)
     return out, report
 
 
@@ -165,8 +172,9 @@ def shuffle(table: Table, key: str, capacity_factor: float = 2.0
         return table, ExchangeReport("shuffle", 0.0, 0.0, elided=True)
     p, cap = table.num_partitions, table.capacity
     pair_cap = pair_capacity(cap, p, capacity_factor)
-    dest = _dest_partition(table.column(key), p)  # (p, cap)
-    return _exchange_by_dest(table, dest, pair_cap, key)
+    with obs.span("op.exchange"):
+        dest = _dest_partition(table.column(key), p)  # (p, cap)
+        return _exchange_by_dest(table, dest, pair_cap, key)
 
 
 # ---------------------------------------------------------------------------
@@ -215,24 +223,25 @@ def hypercube_shuffle(table: Table, dims: tuple[int, ...],
     strides = [1] * len(dims)
     for j in range(len(dims) - 2, -1, -1):
         strides[j] = strides[j + 1] * dims[j + 1]
-    cap = table.capacity
-    wide_cols = {n: jnp.tile(c, (1, f)) for n, c in table.columns.items()}
-    wide_valid = jnp.tile(table.valid, (1, f))
-    dest = jnp.zeros(wide_valid.shape, jnp.int32)
-    for ax, col in axis_keys:
-        coord = (hash32(wide_cols[col], SHUFFLE_SEED)
-                 % jnp.uint32(dims[ax])).astype(jnp.int32)
-        dest = dest + coord * strides[ax]
-    # Replica r of a row takes the r-th combination of free-axis
-    # coordinates (mixed radix over the free shares).
-    rep = jnp.repeat(jnp.arange(f, dtype=jnp.int32), cap)[None, :]
-    rem = jnp.broadcast_to(rep, wide_valid.shape)
-    for ax in free:
-        dest = dest + (rem % dims[ax]) * strides[ax]
-        rem = rem // dims[ax]
-    wide = Table(wide_cols, wide_valid)
-    pair_cap = pair_capacity(cap * f, p, capacity_factor)
-    return _exchange_by_dest(wide, dest, pair_cap, None, kind="hypercube")
+    with obs.span("op.exchange"):
+        cap = table.capacity
+        wide_cols = {n: jnp.tile(c, (1, f)) for n, c in table.columns.items()}
+        wide_valid = jnp.tile(table.valid, (1, f))
+        dest = jnp.zeros(wide_valid.shape, jnp.int32)
+        for ax, col in axis_keys:
+            coord = (hash32(wide_cols[col], SHUFFLE_SEED)
+                     % jnp.uint32(dims[ax])).astype(jnp.int32)
+            dest = dest + coord * strides[ax]
+        # Replica r of a row takes the r-th combination of free-axis
+        # coordinates (mixed radix over the free shares).
+        rep = jnp.repeat(jnp.arange(f, dtype=jnp.int32), cap)[None, :]
+        rem = jnp.broadcast_to(rep, wide_valid.shape)
+        for ax in free:
+            dest = dest + (rem % dims[ax]) * strides[ax]
+            rem = rem // dims[ax]
+        wide = Table(wide_cols, wide_valid)
+        pair_cap = pair_capacity(cap * f, p, capacity_factor)
+        return _exchange_by_dest(wide, dest, pair_cap, None, kind="hypercube")
 
 
 # ---------------------------------------------------------------------------
@@ -281,39 +290,42 @@ def salted_shuffle(a: Table, a_key: str, b: Table, b_key: str, r: int,
         raise ValueError("salted_shuffle expects stacked tables")
     p = a.num_partitions
     r = max(2, int(r))
-    nf = fine_mult * p
-    hot, a_fine = hot_fine_buckets(a, a_key, nf, p, hot_share)
+    with obs.span("op.exchange"):
+        nf = fine_mult * p
+        hot, a_fine = hot_fine_buckets(a, a_key, nf, p, hot_share)
 
-    # Probe: deterministic per-row salt for hot rows (round-robin within the
-    # source partition, offset by the partition id to decorrelate sources).
-    ak = a.column(a_key)
-    a_hot = jnp.take(hot, a_fine)
-    row = jax.lax.broadcasted_iota(jnp.int32, ak.shape, 1)
-    src = jax.lax.broadcasted_iota(jnp.int32, ak.shape, 0)
-    salt_a = jnp.where(a_hot, (row + src) % r, 0).astype(jnp.int32)
-    a_sh, ex_a = _exchange_by_dest(
-        a, _salted_dest(ak, salt_a, p),
-        pair_capacity(a.capacity, p, capacity_factor),
-        None, kind="salted_shuffle")
+        # Probe: deterministic per-row salt for hot rows (round-robin
+        # within the source partition, offset by the partition id to
+        # decorrelate sources).
+        ak = a.column(a_key)
+        a_hot = jnp.take(hot, a_fine)
+        row = jax.lax.broadcasted_iota(jnp.int32, ak.shape, 1)
+        src = jax.lax.broadcasted_iota(jnp.int32, ak.shape, 0)
+        salt_a = jnp.where(a_hot, (row + src) % r, 0).astype(jnp.int32)
+        a_sh, ex_a = _exchange_by_dest(
+            a, _salted_dest(ak, salt_a, p),
+            pair_capacity(a.capacity, p, capacity_factor),
+            None, kind="salted_shuffle")
 
-    # Build: replicate along the capacity axis; replica j of a row is live
-    # iff j == 0 (the plain copy) or the row's key is hot. Replicas of the
-    # same key landing on one partition leave duplicate build keys there —
-    # harmless for FK->PK joins (identical payload, first match wins).
-    bk = b.column(b_key)
-    b_hot = jnp.take(hot, _fine_bucket(bk, nf))
-    cap_b = b.capacity
-    salt_b = jnp.repeat(jnp.arange(r, dtype=jnp.int32), cap_b)[None, :]
-    wide_valid = (jnp.tile(b.valid, (1, r))
-                  & ((salt_b == 0) | jnp.tile(b_hot, (1, r))))
-    b_wide = Table({n: jnp.tile(c, (1, r)) for n, c in b.columns.items()},
-                   wide_valid)
-    dest_b = _salted_dest(jnp.tile(bk, (1, r)),
-                          jnp.broadcast_to(salt_b, wide_valid.shape), p)
-    b_sh, ex_b = _exchange_by_dest(
-        b_wide, dest_b, pair_capacity(cap_b, p, capacity_factor),
-        None, kind="salted_shuffle")
-    return a_sh, b_sh, ex_a, ex_b
+        # Build: replicate along the capacity axis; replica j of a row is
+        # live iff j == 0 (the plain copy) or the row's key is hot. Replicas
+        # of the same key landing on one partition leave duplicate build
+        # keys there — harmless for FK->PK joins (identical payload, first
+        # match wins).
+        bk = b.column(b_key)
+        b_hot = jnp.take(hot, _fine_bucket(bk, nf))
+        cap_b = b.capacity
+        salt_b = jnp.repeat(jnp.arange(r, dtype=jnp.int32), cap_b)[None, :]
+        wide_valid = (jnp.tile(b.valid, (1, r))
+                      & ((salt_b == 0) | jnp.tile(b_hot, (1, r))))
+        b_wide = Table({n: jnp.tile(c, (1, r)) for n, c in b.columns.items()},
+                       wide_valid)
+        dest_b = _salted_dest(jnp.tile(bk, (1, r)),
+                              jnp.broadcast_to(salt_b, wide_valid.shape), p)
+        b_sh, ex_b = _exchange_by_dest(
+            b_wide, dest_b, pair_capacity(cap_b, p, capacity_factor),
+            None, kind="salted_shuffle")
+        return a_sh, b_sh, ex_a, ex_b
 
 
 def key_skew(table: Table, key: str, p: int | None = None,
@@ -329,8 +341,8 @@ def key_skew(table: Table, key: str, p: int | None = None,
     p = p or table.num_partitions
     dest = jnp.where(table.valid, _dest_partition(table.column(key), p), -1)
     counts = kops.hist(dest.reshape(-1), nd=p)
-    total = int(jnp.sum(counts))
+    total = int(obs.fetch(jnp.sum(counts)))
     if total == 0:
         return 1.0
-    s = float(jnp.max(counts)) * p / total
+    s = float(obs.fetch(jnp.max(counts))) * p / total
     return s if s >= floor else 1.0

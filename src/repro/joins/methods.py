@@ -19,6 +19,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.cost_model import JoinMethod
 from ..kernels import ops as kops
 from .exchange import (ExchangeReport, broadcast, hypercube_shuffle,
@@ -234,6 +235,7 @@ def cartesian_join(a: Table, b: Table,
         network_bytes=(p - 1) / p * (a.count() * a.row_bytes
                                      + rows_b * b_full.row_bytes),
         local_bytes=(a.count() * a.row_bytes + rows_b * b_full.row_bytes) / p)
+    obs.count("exchange_bytes", shuffle_like.network_bytes)
     nl_bytes = float(a.count() * a.row_bytes
                      + a.count() / p * rows_b * b_full.row_bytes)
     rep = JoinReport(JoinMethod.CARTESIAN, [shuffle_like], nl_bytes,
@@ -294,67 +296,71 @@ def hypercube_multiway_join(tables: list, spec: HypercubeSpec,
     exactly once (no cross-partition dedup needed). The probe relation is
     index 0; its rows (with gathered build payloads) form the output.
     """
-    shards: list[Table] = []
-    exs: list[ExchangeReport] = []
-    for t, ak in zip(tables, spec.axis_keys):
-        sh, ex = hypercube_shuffle(t, spec.dims, tuple(ak), capacity_factor)
-        shards.append(sh)
-        exs.append(ex)
+    with obs.span("op.local_join"):
+        shards: list[Table] = []
+        exs: list[ExchangeReport] = []
+        for t, ak in zip(tables, spec.axis_keys):
+            sh, ex = hypercube_shuffle(t, spec.dims, tuple(ak),
+                                       capacity_factor)
+            shards.append(sh)
+            exs.append(ex)
 
-    probe = shards[0]
-    cols = dict(probe.columns)
-    valid = probe.valid
+        probe = shards[0]
+        cols = dict(probe.columns)
+        valid = probe.valid
 
-    fused = (use_kernel and len(spec.links) == 2
-             and all(lk.probe_col in probe.columns for lk in spec.links))
-    if fused:
-        # 3-way case on the TPU path: both probe key columns stream through
-        # one fused Pallas kernel (dense in-partition match; build keys are
-        # unique so first-match is exact).
-        l1, l2 = spec.links
-        b1, b2 = shards[l1.build], shards[l2.build]
-        idx1, idx2 = kops.probe3(  # one probe per partition
-            jnp.where(valid, cols[l1.probe_col], A_SENTINEL).astype(jnp.int32),
-            jnp.where(valid, cols[l2.probe_col], A_SENTINEL).astype(jnp.int32),
-            _sanitized(b1, l1.build_col, B_SENTINEL),
-            _sanitized(b2, l2.build_col, B_SENTINEL))
-        for b, idx in ((b1, idx1), (b2, idx2)):
-            gathered = jax.vmap(lambda bc, ix: gather_rows(bc, ix)[0])(
-                b.columns, jnp.maximum(idx, 0))
-            for name, col in gathered.items():
-                if name in cols:
-                    raise ValueError(f"duplicate column {name!r} in "
-                                     "multi-way join")
-                cols[name] = col
-            valid = valid & (idx >= 0)
-    else:
-        for lk in spec.links:
-            b = shards[lk.build]
-            res = jax.vmap(
-                lambda ak_, av, bk, bv: hash_join(ak_, av, bk, bv,
-                                                  use_kernel=use_kernel)
-            )(cols[lk.probe_col], valid, b.column(lk.build_col), b.valid)
-            gathered = jax.vmap(lambda bc, ix: gather_rows(bc, ix)[0])(
-                b.columns, jnp.maximum(res.match_idx, 0))
-            for name, col in gathered.items():
-                if name in cols:
-                    raise ValueError(f"duplicate column {name!r} in "
-                                     "multi-way join")
-                cols[name] = col
-            valid = valid & res.found
+        fused = (use_kernel and len(spec.links) == 2
+                 and all(lk.probe_col in probe.columns for lk in spec.links))
+        if fused:
+            # 3-way case on the TPU path: both probe key columns stream through
+            # one fused Pallas kernel (dense in-partition match; build keys are
+            # unique so first-match is exact).
+            l1, l2 = spec.links
+            b1, b2 = shards[l1.build], shards[l2.build]
+            idx1, idx2 = kops.probe3(  # one probe per partition
+                jnp.where(valid, cols[l1.probe_col], A_SENTINEL
+                          ).astype(jnp.int32),
+                jnp.where(valid, cols[l2.probe_col], A_SENTINEL
+                          ).astype(jnp.int32),
+                _sanitized(b1, l1.build_col, B_SENTINEL),
+                _sanitized(b2, l2.build_col, B_SENTINEL))
+            for b, idx in ((b1, idx1), (b2, idx2)):
+                gathered = jax.vmap(lambda bc, ix: gather_rows(bc, ix)[0])(
+                    b.columns, jnp.maximum(idx, 0))
+                for name, col in gathered.items():
+                    if name in cols:
+                        raise ValueError(f"duplicate column {name!r} in "
+                                         "multi-way join")
+                    cols[name] = col
+                valid = valid & (idx >= 0)
+        else:
+            for lk in spec.links:
+                b = shards[lk.build]
+                res = jax.vmap(
+                    lambda ak_, av, bk, bv: hash_join(ak_, av, bk, bv,
+                                                      use_kernel=use_kernel)
+                )(cols[lk.probe_col], valid, b.column(lk.build_col), b.valid)
+                gathered = jax.vmap(lambda bc, ix: gather_rows(bc, ix)[0])(
+                    b.columns, jnp.maximum(res.match_idx, 0))
+                for name, col in gathered.items():
+                    if name in cols:
+                        raise ValueError(f"duplicate column {name!r} in "
+                                         "multi-way join")
+                    cols[name] = col
+                valid = valid & res.found
 
-    for c1, c2 in spec.checks:
-        valid = valid & (cols[c1] == cols[c2])
+        for c1, c2 in spec.checks:
+            valid = valid & (cols[c1] == cols[c2])
 
-    out = Table(cols, valid)
-    out.partitioned_by = None
-    # Measured local workload mirrors the binary methods' convention: one
-    # probe pass over the (replicated) probe side, build + probe touch of
-    # each (replicated) build side.
-    local = float(probe.count() * probe.row_bytes
-                  + sum(2.0 * s.count() * s.row_bytes for s in shards[1:]))
-    rep = JoinReport(JoinMethod.HYPERCUBE_SHUFFLE, exs, local, out.count())
-    return out, rep
+        out = Table(cols, valid)
+        out.partitioned_by = None
+        # Measured local workload mirrors the binary methods' convention: one
+        # probe pass over the (replicated) probe side, build + probe touch of
+        # each (replicated) build side.
+        local = float(probe.count() * probe.row_bytes
+                      + sum(2.0 * s.count() * s.row_bytes for s in shards[1:]))
+        rep = JoinReport(JoinMethod.HYPERCUBE_SHUFFLE, exs, local, out.count())
+        return out, rep
 
 
 # ---------------------------------------------------------------------------
@@ -373,21 +379,24 @@ def run_equi_join(method: JoinMethod, a: Table, b: Table, a_key: str,
                   capacity_factor: float = 2.0,
                   salt_r: int = 2) -> tuple[Table, JoinReport]:
     """Dispatch an equi-join to the selected physical method."""
-    if method in (JoinMethod.BROADCAST_NL, JoinMethod.CARTESIAN):
-        pred = lambda ac, bc: ac[a_key] == bc[b_key]  # noqa: E731
-        fn = (broadcast_nl_join if method is JoinMethod.BROADCAST_NL
-              else cartesian_join)
-        return fn(a, b, pred, join_type, b_key)
-    if method is JoinMethod.BROADCAST_HASH:
-        return broadcast_hash_join(a, b, a_key, b_key, join_type, use_kernel)
-    if method is JoinMethod.SHUFFLE_HASH:
-        return shuffle_hash_join(a, b, a_key, b_key, join_type,
-                                 capacity_factor, use_kernel)
-    if method is JoinMethod.SALTED_SHUFFLE_HASH:
-        # salt_r < 2 (e.g. a bare hint) is clamped inside salted_shuffle.
-        return salted_shuffle_hash_join(a, b, a_key, b_key, join_type,
-                                        salt_r, capacity_factor, use_kernel)
-    if method is JoinMethod.SHUFFLE_SORT:
-        return shuffle_sort_join(a, b, a_key, b_key, join_type,
-                                 capacity_factor, use_kernel)
-    raise ValueError(f"unknown method {method}")
+    with obs.span("op.local_join"):
+        if method in (JoinMethod.BROADCAST_NL, JoinMethod.CARTESIAN):
+            pred = lambda ac, bc: ac[a_key] == bc[b_key]  # noqa: E731
+            fn = (broadcast_nl_join if method is JoinMethod.BROADCAST_NL
+                  else cartesian_join)
+            return fn(a, b, pred, join_type, b_key)
+        if method is JoinMethod.BROADCAST_HASH:
+            return broadcast_hash_join(a, b, a_key, b_key, join_type,
+                                       use_kernel)
+        if method is JoinMethod.SHUFFLE_HASH:
+            return shuffle_hash_join(a, b, a_key, b_key, join_type,
+                                     capacity_factor, use_kernel)
+        if method is JoinMethod.SALTED_SHUFFLE_HASH:
+            # salt_r < 2 (e.g. a bare hint) is clamped inside salted_shuffle.
+            return salted_shuffle_hash_join(a, b, a_key, b_key, join_type,
+                                            salt_r, capacity_factor,
+                                            use_kernel)
+        if method is JoinMethod.SHUFFLE_SORT:
+            return shuffle_sort_join(a, b, a_key, b_key, join_type,
+                                     capacity_factor, use_kernel)
+        raise ValueError(f"unknown method {method}")

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.stats import StatsSource, TableStats
 
 
@@ -83,7 +84,7 @@ class Table:
 
     def count(self) -> int:
         """Concrete number of valid rows (host sync)."""
-        return int(jnp.sum(self.valid))
+        return int(obs.fetch(jnp.sum(self.valid)))
 
     def measure(self) -> TableStats:
         """Adaptive runtime statistic of this materialized dataset."""
@@ -94,10 +95,10 @@ class Table:
 
     def to_numpy(self) -> Dict[str, np.ndarray]:
         """Compacted valid rows as numpy (host-side; for tests/oracles)."""
-        v = np.asarray(self.valid).reshape(-1)
+        v = np.asarray(obs.fetch(self.valid)).reshape(-1)
         out = {}
         for n, c in self.columns.items():
-            out[n] = np.asarray(c).reshape(-1)[v]
+            out[n] = np.asarray(obs.fetch(c)).reshape(-1)[v]
         return out
 
 
@@ -150,13 +151,15 @@ def compact_partitions(table: Table, capacity: int | None = None,
     """
     if not table.stacked:
         raise ValueError("compact expects a stacked table")
-    need = int(_max_live(table.valid))
-    cap = capacity or max(8, 1 << (max(int(need * slack), 1) - 1).bit_length())
-    cap = min(cap, table.capacity)
-    order = _front_order(table.valid, cap)
-    cols = {n: jnp.take_along_axis(c, order, axis=1)
-            for n, c in table.columns.items()}
-    valid = jnp.take_along_axis(table.valid, order, axis=1)
+    with obs.span("op.compact"):
+        need = int(obs.fetch(_max_live(table.valid)))
+        cap = capacity or max(
+            8, 1 << (max(int(need * slack), 1) - 1).bit_length())
+        cap = min(cap, table.capacity)
+        order = _front_order(table.valid, cap)
+        cols = {n: jnp.take_along_axis(c, order, axis=1)
+                for n, c in table.columns.items()}
+        valid = jnp.take_along_axis(table.valid, order, axis=1)
     return Table(cols, valid, table.partitioned_by)
 
 

@@ -5,6 +5,9 @@ CPU CI container) they run in interpret mode (the kernel body executes in
 Python, validating the exact TPU program). The backend decides, never a
 caller: code outside ``repro.kernels`` uses these wrappers, and the kernel
 functions themselves take a required ``interpret`` flag.
+
+Each wrapper counts its calls from Python as ``obs`` counter
+``kernel.<name>``: one per eager call, one per trace under jit.
 """
 
 from __future__ import annotations
@@ -14,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import bloom as _bloom
 from . import zone_map as _zone_map
 from .bitonic_sort import MAX_TILE, bitonic_sort_tile
@@ -24,16 +28,6 @@ from .tiled_probe import tiled_probe, tiled_probe3
 merge_ranges = _zone_map.merge_ranges
 range_probe = _zone_map.range_probe
 
-#: ``jax.monitoring`` event recorded per kernel call from Python (one per
-#: eager call, one per trace under jit): ``EVENT_PREFIX + kernel name``.
-#: A listener can count which kernels a run reached; without one the
-#: record is a no-op.
-EVENT_PREFIX = "/repro/kernels/"
-
-
-def _called(kernel: str) -> None:
-    jax.monitoring.record_event(EVENT_PREFIX + kernel)
-
 
 @functools.cache
 def _interpret() -> bool:
@@ -43,41 +37,41 @@ def _interpret() -> bool:
 def probe(a_keys: jax.Array, b_keys: jax.Array) -> jax.Array:
     """First-match index of each probe key in the build keys (-1 if none),
     per row of any leading batch dimensions."""
-    _called("tiled_probe")
+    obs.count("kernel.tiled_probe")
     return tiled_probe(a_keys, b_keys, interpret=_interpret())
 
 
 def probe3(a1_keys: jax.Array, a2_keys: jax.Array, b_keys: jax.Array,
            c_keys: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Fused two-build first-match probe (hypercube 3-way local join)."""
-    _called("tiled_probe3")
+    obs.count("kernel.tiled_probe3")
     return tiled_probe3(a1_keys, a2_keys, b_keys, c_keys,
                         interpret=_interpret())
 
 
 def hist(dest: jax.Array, nd: int) -> jax.Array:
     """Partition-destination histogram (skew/capacity statistics)."""
-    _called("partition_hist")
+    obs.count("kernel.partition_hist")
     return partition_hist(dest, nd=nd, interpret=_interpret())
 
 
 def bloom_build(keys: jax.Array, valid: jax.Array | None = None, *,
                 m_bits: int, k: int) -> jax.Array:
     """Bit-packed (m_bits/32,) uint32 bloom filter of the valid keys."""
-    _called("bloom_build")
+    obs.count("kernel.bloom_build")
     return _bloom.bloom_build(keys, valid, m_bits=m_bits, k=k,
                               interpret=_interpret())
 
 
 def bloom_probe(keys: jax.Array, bits: jax.Array, *, k: int) -> jax.Array:
     """Keep-mask of ``keys`` against a ``bloom_build`` filter."""
-    _called("bloom_probe")
+    obs.count("kernel.bloom_probe")
     return _bloom.bloom_probe(keys, bits, k=k, interpret=_interpret())
 
 
 def key_range(keys: jax.Array, valid: jax.Array | None = None) -> jax.Array:
     """(min, max) of the valid keys: the zone-map build."""
-    _called("key_range")
+    obs.count("kernel.key_range")
     return _zone_map.key_range(keys, valid, interpret=_interpret())
 
 
@@ -90,7 +84,7 @@ def sort_pairs(keys: jax.Array, values: jax.Array):
     """
     n = keys.shape[0]
     if n and not (n & (n - 1)) and n <= MAX_TILE:
-        _called("bitonic_sort_tile")
+        obs.count("kernel.bitonic_sort_tile")
         return bitonic_sort_tile(keys, values, interpret=_interpret())
     order = jnp.argsort(keys)
     return keys[order], values[order]

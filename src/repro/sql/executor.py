@@ -21,6 +21,7 @@ from typing import Dict, List, Optional
 
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.cost_model import (BLOOM_DEFAULT_BITS_PER_KEY,
                                DEFAULT_REOPT_QERROR, CostParams, JoinMethod,
                                filter_reduce_cost, runtime_filter_cost)
@@ -361,7 +362,7 @@ class Executor:
             plan = rewritten
         t0 = time.perf_counter()
         ann = self._eval(plan)
-        ann.table.valid.block_until_ready()
+        obs.wait(ann.table.valid)
         dt = time.perf_counter() - t0
         net = sum(d.network_bytes for d in self._decisions)
         net += sum(f.network_bytes for f in self._filters)
@@ -600,48 +601,49 @@ class Executor:
         another runtime filter of this query); a cached *lookup* is still
         safe there, since the chain-keyed payload is a superset (false
         positives only, never false negatives)."""
-        payload = None
-        ck = None
-        if self.filter_cache is not None:
-            ck = filter_cache_key(build_leaf, rf.build_key, rf.kind,
-                                  rf.m_bits, rf.k)
-            payload = self.filter_cache.lookup(ck)
-        cached = payload is not None
-        if cached and self.verify and ck is not None:
-            # F3 reuse side: the cache keys payloads by (chain, key, kind,
-            # shape), so a hit's stored chain must be subset-safe for this
-            # edge's chain. Exact-key hits make this trivially true today;
-            # the gate pins it against a future key loosening.
-            self._gate(check_cache_reuse((ck[0], ck[1]),
-                                         predicate_chain(build_leaf)))
-        if payload is None:
-            payload = build_filter_payload(rf, build)
-            if self.filter_cache is not None and cacheable:
-                if self.verify:
-                    # F3 store side: only chain-faithful payloads may enter
-                    # the cross-query cache.
-                    self._gate(check_cache_store(
-                        predicate_chain(build_leaf),
-                        build_masked=not cacheable))
-                # Store the *materialized* build table's measurement, not
-                # the planner's ``build_stats`` quote: the payload was
-                # just built from the real rows, so the true cardinality
-                # is free — and in a static run the quote is merely
-                # ESTIMATED, which the cache's RUNTIME guard (rightly)
-                # refuses to treat as a measurement.
-                self.filter_cache.store(ck, payload, build.measure())
-        keep = probe_filter_mask(rf, payload,
-                                 probe.table.column(rf.probe_key))
-        table = probe.table.with_valid(probe.table.valid & keep)
-        measured = table.measure()
-        decision = FilterDecision(rf, probe.table.count(),
-                                  int(measured.cardinality),
-                                  self.p, cached=cached)
-        if self.verify:
-            self._gate(audit_filter_decision(decision))
-        self._filters.append(decision)
-        return _Annotated(table, measured,
-                          probe.estimated.scaled(rf.keep_est))
+        with obs.span("op.filter"):
+            payload = None
+            ck = None
+            if self.filter_cache is not None:
+                ck = filter_cache_key(build_leaf, rf.build_key, rf.kind,
+                                      rf.m_bits, rf.k)
+                payload = self.filter_cache.lookup(ck)
+            cached = payload is not None
+            if cached and self.verify and ck is not None:
+                # F3 reuse side: the cache keys payloads by (chain, key, kind,
+                # shape), so a hit's stored chain must be subset-safe for this
+                # edge's chain. Exact-key hits make this trivially true today;
+                # the gate pins it against a future key loosening.
+                self._gate(check_cache_reuse((ck[0], ck[1]),
+                                             predicate_chain(build_leaf)))
+            if payload is None:
+                payload = build_filter_payload(rf, build)
+                if self.filter_cache is not None and cacheable:
+                    if self.verify:
+                        # F3 store side: only chain-faithful payloads may enter
+                        # the cross-query cache.
+                        self._gate(check_cache_store(
+                            predicate_chain(build_leaf),
+                            build_masked=not cacheable))
+                    # Store the *materialized* build table's measurement, not
+                    # the planner's ``build_stats`` quote: the payload was
+                    # just built from the real rows, so the true cardinality
+                    # is free — and in a static run the quote is merely
+                    # ESTIMATED, which the cache's RUNTIME guard (rightly)
+                    # refuses to treat as a measurement.
+                    self.filter_cache.store(ck, payload, build.measure())
+            keep = probe_filter_mask(rf, payload,
+                                     probe.table.column(rf.probe_key))
+            table = probe.table.with_valid(probe.table.valid & keep)
+            measured = table.measure()
+            decision = FilterDecision(rf, probe.table.count(),
+                                      int(measured.cardinality),
+                                      self.p, cached=cached)
+            if self.verify:
+                self._gate(audit_filter_decision(decision))
+            self._filters.append(decision)
+            return _Annotated(table, measured,
+                              probe.estimated.scaled(rf.keep_est))
 
     # -- join execution --------------------------------------------------------
 
@@ -654,32 +656,32 @@ class Executor:
         # join key gets its shuffle elided by the engine, so the model's
         # shuffle-family quotes drop that side's network term (the
         # redundant-exchange finding plan analysis rule E2 pins).
-        props = JoinProperties(join_type=join_type, hint=hint,
-                               left_partitioned=(left.table.partitioned_by
-                                                 == lk),
-                               right_partitioned=(right.table.partitioned_by
-                                                  == rk))
-        if self.skew_aware:
-            # Adaptive runtime statistic beyond (size, cardinality): the
-            # join-key straggler factor from per-partition load histograms.
-            # A side already hash-partitioned by its join key keeps the
-            # uniform default: its shuffle would be *elided* (§3.7's
-            # C_shuffle = 0 case), so charging a straggler — or salting,
-            # which un-elides the exchange — would regress exactly the
-            # plans the elision optimizes.
-            if left.table.partitioned_by != lk:
-                lstats = lstats.with_skew(
-                    key_skew(left.table, lk, self.p, self.skew_floor))
-            if right.table.partitioned_by != rk:
-                rstats = rstats.with_skew(
-                    key_skew(right.table, rk, self.p, self.skew_floor))
-        sel = self.strategy.select(lstats, rstats, props, self.p)
-        sel = self._engine_feasible(sel, lstats, rstats, props)
-        if self.verify:
-            # Pre-run cost audit (C1/C2/S1): a bad selection is caught
-            # before any bytes move.
-            self._gate(audit_selection(sel, lstats, rstats, props,
-                                       self._params))
+        with obs.span("op.select"):
+            props = JoinProperties(
+                join_type=join_type, hint=hint,
+                left_partitioned=left.table.partitioned_by == lk,
+                right_partitioned=right.table.partitioned_by == rk)
+            if self.skew_aware:
+                # Adaptive runtime statistic beyond (size, cardinality): the
+                # join-key straggler factor from per-partition load histograms.
+                # A side already hash-partitioned by its join key keeps the
+                # uniform default: its shuffle would be *elided* (§3.7's
+                # C_shuffle = 0 case), so charging a straggler — or salting,
+                # which un-elides the exchange — would regress exactly the
+                # plans the elision optimizes.
+                if left.table.partitioned_by != lk:
+                    lstats = lstats.with_skew(
+                        key_skew(left.table, lk, self.p, self.skew_floor))
+                if right.table.partitioned_by != rk:
+                    rstats = rstats.with_skew(
+                        key_skew(right.table, rk, self.p, self.skew_floor))
+            sel = self.strategy.select(lstats, rstats, props, self.p)
+            sel = self._engine_feasible(sel, lstats, rstats, props)
+            if self.verify:
+                # Pre-run cost audit (C1/C2/S1): a bad selection is caught
+                # before any bytes move.
+                self._gate(audit_selection(sel, lstats, rstats, props,
+                                           self._params))
         out, rep = self._run_join_with_retry(sel, left.table, right.table,
                                              lk, rk, join_type.value)
         if self.compact:
@@ -687,7 +689,8 @@ class Executor:
         if self.verify:
             # Post-run exchange audit (E1/E2): every elision proven
             # necessary, every proven partitioning actually elided.
-            self._gate(audit_exchanges(sel, props, rep))
+            with obs.span("op.select"):
+                self._gate(audit_exchanges(sel, props, rep))
         self._decisions.append(JoinDecision(sel, lstats, rstats, rep,
                                             props=props))
         measured = out.measure()
@@ -1012,9 +1015,11 @@ class Executor:
         build = max(hp.order[1:], key=lambda i: stats[i].size_bytes)
         props = JoinProperties()
         if self.verify:
-            self._gate(audit_selection(hp.selection, stats[probe],
-                                       stats[build], props, self._params))
-            self._gate(audit_exchanges(hp.selection, props, rep))
+            with obs.span("op.select"):
+                self._gate(audit_selection(hp.selection, stats[probe],
+                                           stats[build], props,
+                                           self._params))
+                self._gate(audit_exchanges(hp.selection, props, rep))
         self._decisions.append(JoinDecision(hp.selection, stats[probe],
                                             stats[build], rep, props=props))
         est = anns[probe].estimated
@@ -1074,6 +1079,11 @@ class Executor:
 
 
 def _apply_filter(table: Table, f: Filter) -> Table:
+    with obs.span("op.filter"):
+        return table.with_valid(table.valid & _filter_mask(table, f))
+
+
+def _filter_mask(table: Table, f: Filter) -> jnp.ndarray:
     c = table.column(f.column)
     if f.op == "eq":
         m = c == f.value
@@ -1101,4 +1111,4 @@ def _apply_filter(table: Table, f: Filter) -> Table:
         m = c == table.column(str(f.column2))
     else:
         raise ValueError(f"unknown filter op {f.op}")
-    return table.with_valid(table.valid & m)
+    return m

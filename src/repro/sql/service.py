@@ -45,9 +45,11 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import hashlib
 import time
 from typing import Deque, Dict, List, Optional, Tuple, Union
 
+from .. import obs
 from ..core.cost_model import CostParams
 from ..joins.table import Table
 from .binder import parse_sql
@@ -197,18 +199,21 @@ class QueryService:
                name: Optional[str] = None) -> Submission:
         """Admit one query (SQL text or logical plan): lower, compile (or
         hit the plan cache), quote, enqueue."""
-        plan = parse_sql(query) if isinstance(query, str) else query
-        hits_before = self.plan_cache.hits
-        optimized = self._optimize(plan, plan_cache=self.plan_cache)
-        sub = Submission(
-            qid=self._qid,
-            name=name if name is not None else f"q{self._qid}",
-            plan=plan,
-            optimized=optimized,
-            quoted_cost=modeled_plan_cost(optimized.plan, self._base_stats,
-                                          self._schema, self._params,
-                                          self.catalog.key_domains),
-            plan_cached=self.plan_cache.hits > hits_before)
+        name = name if name is not None else f"q{self._qid}"
+        with obs.span("service.submit", query=name):
+            plan = parse_sql(query) if isinstance(query, str) else query
+            hits_before = self.plan_cache.hits
+            optimized = self._optimize(plan, plan_cache=self.plan_cache)
+            sub = Submission(
+                qid=self._qid,
+                name=name,
+                plan=plan,
+                optimized=optimized,
+                quoted_cost=modeled_plan_cost(optimized.plan,
+                                              self._base_stats,
+                                              self._schema, self._params,
+                                              self.catalog.key_domains),
+                plan_cached=self.plan_cache.hits > hits_before)
         self._qid += 1
         self.admission.submit(sub)
         return sub
@@ -234,7 +239,9 @@ class QueryService:
         batch, in admission order."""
         reports = []
         while len(self.admission):
-            reports.append(self._execute_batch(self.admission.next_batch()))
+            with obs.span("service.batch"):
+                reports.append(
+                    self._execute_batch(self.admission.next_batch()))
         return reports
 
     def _execute_batch(self, batch: List[Submission]) -> BatchReport:
@@ -259,14 +266,20 @@ class QueryService:
             for sig in sorted(shared_sigs,
                               key=lambda s: subtree_size(info[s][0])):
                 node, count, consumers = info[sig]
-                res = self._executor(intermediates).execute(node)
+                # The span carries a digest: signatures hold the ',' and
+                # '=' that separate a trace event's metadata.
+                digest = hashlib.blake2b(sig.encode(),
+                                         digest_size=6).hexdigest()
+                with obs.span("service.shared", sig=digest):
+                    res = self._executor(intermediates).execute(node)
                 intermediates[sig] = res.table
                 shared.append(SharedSubtree(sig, node, tuple(consumers),
                                             count, res))
         results: Dict[str, ExecutionResult] = {}
         for sub in batch:
-            results[sub.name] = self._executor(intermediates).execute(
-                sub.optimized.plan)
+            with obs.span("service.query", query=sub.name):
+                results[sub.name] = self._executor(intermediates).execute(
+                    sub.optimized.plan)
         return BatchReport(results, shared, time.perf_counter() - t0)
 
     def execute_solo(self, query: Union[str, Node]) -> ExecutionResult:

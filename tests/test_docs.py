@@ -14,6 +14,7 @@ import re
 import pytest
 
 import repro.core.cost_model as cost_model
+import repro.obs as obs
 import repro.sql.binder as sql_binder
 import repro.sql.parser as sql_parser
 import repro.sql.plan_analysis as plan_analysis
@@ -149,6 +150,17 @@ def test_architecture_links_to_serving():
     arch = (DOCS / "architecture.md").read_text()
     assert "](serving.md)" in arch, (
         "docs/architecture.md no longer links to docs/serving.md")
+
+
+def test_observability_doc_names_every_span_and_counter():
+    """docs/observability.md backticks every span and counter the engine
+    uses (``obs.SPANS``, ``obs.COUNTERS``): an operator reading a trace
+    finds each name explained."""
+    doc = (DOCS / "observability.md").read_text()
+    documented = set(re.findall(r"`([A-Za-z_][A-Za-z0-9_.]*)`", doc))
+    missing = (set(obs.SPANS) | set(obs.COUNTERS)) - documented
+    assert not missing, (
+        f"docs/observability.md is missing {sorted(missing)}")
 
 
 def _markdown_files():
