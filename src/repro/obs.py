@@ -52,6 +52,7 @@ KERNELS = ("tiled_probe", "tiled_probe3", "partition_hist", "bloom_build",
 #: Every counter name.
 COUNTERS = ("host_syncs",       # obs.fetch and obs.wait calls
             "exchange_bytes",   # network bytes of every exchange
+            "pruned_columns",   # scan columns QueryService pruned away
             ) + tuple(f"kernel.{k}" for k in KERNELS)
 
 _SPANS = frozenset(SPANS)

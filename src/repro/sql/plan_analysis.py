@@ -346,16 +346,20 @@ def analyze_plan(plan: Node, schema: Schema,
 
 
 def check_schema_preserved(before: Node, after: Node, schema: Schema,
-                           path: str = "root") -> List[Violation]:
-    """P2: an optimizer rewrite preserves the plan's output column set."""
+                           path: str = "root",
+                           required=None) -> List[Violation]:
+    """P2: an optimizer rewrite preserves the plan's output column set.
+    Given ``required``, it keeps those of the columns and adds none (a
+    shared subtree pruned to what its consumers read)."""
     try:
-        want = set(leaf_columns(before, schema))
+        have = set(leaf_columns(before, schema))
         got = set(leaf_columns(after, schema))
     except (KeyError, TypeError):
         return []  # unresolvable schema is P1 territory, reported there
-    if want == got:
+    want = have if required is None else have & set(required)
+    lost, gained = sorted(want - got), sorted(got - have)
+    if not lost and not gained:
         return []
-    lost, gained = sorted(want - got), sorted(got - want)
     return [_v("P2_OUTPUT_SCHEMA_CHANGED", path,
                f"rewrite changed output columns (lost {lost}, "
                f"gained {gained})")]

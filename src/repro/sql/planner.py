@@ -609,11 +609,17 @@ def _sink(f: Filter, schema: Schema) -> Node:
     return f
 
 
-def prune_projections(node: Node, schema: Schema,
-                      required=None) -> Node:
+def prune_projections(node: Node, schema: Schema, required=None,
+                      barriers: Optional[Dict[str, set]] = None) -> Node:
     """Narrow scans to the columns the plan consumes (top-down required-set
     propagation). The root's output columns are always preserved, so the
-    rewrite never changes query results."""
+    rewrite never changes query results.
+
+    ``barriers`` maps the signatures of subtrees shared across a batch to
+    the columns their consumers read. A Join or Aggregate whose signature
+    is a key is returned unchanged, so it still matches the shared table,
+    and the columns required of it are added to its entry: the union over
+    every plan pruned with the same ``barriers``."""
     try:
         cols = leaf_columns(node, schema)
     except (KeyError, TypeError):
@@ -622,6 +628,11 @@ def prune_projections(node: Node, schema: Schema,
         required = set(cols)
     required = set(required) & set(cols)
 
+    if barriers and isinstance(node, (Join, Aggregate)):
+        need = barriers.get(signature(node))
+        if need is not None:
+            need |= required
+            return node
     if isinstance(node, Scan):
         keep = tuple(c for c in schema[node.table] if c in required)
         if keep and len(keep) < len(schema[node.table]):
@@ -632,17 +643,17 @@ def prune_projections(node: Node, schema: Schema,
         if node.column2 is not None:
             need |= {node.column2}
         return dataclasses.replace(
-            node, child=prune_projections(node.child, schema, need))
+            node, child=prune_projections(node.child, schema, need, barriers))
     if isinstance(node, Project):
         keep = tuple(c for c in node.columns if c in required)
         if not keep:
             keep = node.columns
-        child = prune_projections(node.child, schema, set(keep))
+        child = prune_projections(node.child, schema, set(keep), barriers)
         return dataclasses.replace(node, child=child, columns=keep)
     if isinstance(node, Aggregate):
         need = {node.key} | {col for col, _ in node.aggs}
         return dataclasses.replace(
-            node, child=prune_projections(node.child, schema, need))
+            node, child=prune_projections(node.child, schema, need, barriers))
     if isinstance(node, Join):
         try:
             lcols = set(leaf_columns(node.left, schema))
@@ -652,15 +663,25 @@ def prune_projections(node: Node, schema: Schema,
         if lcols & rcols:
             # Colliding names get order-dependent ``_r`` renames — pruning
             # could silently change output naming. Recurse with full sets.
-            return dataclasses.replace(
-                node, left=prune_projections(node.left, schema),
-                right=prune_projections(node.right, schema))
-        lneed = (required & lcols) | {node.left_key}
-        rneed = (required & rcols) | {node.right_key}
+            lneed, rneed = None, None
+        else:
+            lneed = (required & lcols) | {node.left_key}
+            rneed = (required & rcols) | {node.right_key}
         return dataclasses.replace(
-            node, left=prune_projections(node.left, schema, lneed),
-            right=prune_projections(node.right, schema, rneed))
+            node, left=prune_projections(node.left, schema, lneed, barriers),
+            right=prune_projections(node.right, schema, rneed, barriers))
     return node
+
+
+def scan_columns(node: Node, schema: Schema) -> int:
+    """Columns the plan's scans yield, summed over every scan: a Scan
+    directly under a Project yields the Project's columns, any other Scan
+    its whole table."""
+    if isinstance(node, Project) and isinstance(node.child, Scan):
+        return len(node.columns)
+    if isinstance(node, Scan):
+        return len(schema[node.table])
+    return sum(scan_columns(c, schema) for c in node.children())
 
 
 # ---------------------------------------------------------------------------

@@ -28,12 +28,19 @@ amortizing work three ways:
 Correctness contract: per-query results are identical to solo execution
 (``execute_solo``). CSE only dedupes occurrences that solo execution
 evaluates as a self-contained exchange boundary (the region-atomicity
-rule in ``shared_subtree_candidates``), runtime filters never change
-result rows, and the service optimizes with ``prune=False`` — projection
-pruning narrows scans per *whole-plan* column sets, which would make
-structurally-shared subtrees signature-distinct (the classic CSE /
-column-pruning tension; a shared subtree must carry every column any
-consumer needs).
+rule in ``shared_subtree_candidates``), and runtime filters never change
+result rows.
+
+Projection pruning runs per batch, after CSE discovery. ``submit``
+optimizes with ``prune=False``, so plan-cache keys, quotes and shared
+signatures are those of the full plans. ``_execute_batch`` then narrows
+every executed plan to the columns it reads (``prune_projections``),
+with the shared subtrees as barriers: a consumer keeps each shared
+subtree unchanged, so it still matches the producer's table by
+signature, and each producer is pruned to the union of the columns its
+consumers read (the classic CSE / column-pruning tension, settled by
+the union). ``execute_solo`` prunes the same way with no barriers. The
+counter ``pruned_columns`` sums the scan columns removed.
 
 Run ``python -m repro.sql.service`` for the standalone CI pass: the
 service suite (q19-q23 + the deliberately-overlapping q33/q34) executes
@@ -56,8 +63,11 @@ from .binder import parse_sql
 from .datagen import Catalog
 from .executor import ExecutionResult, Executor
 from .logical import Node, shared_subtree_candidates, subtree_size
+from .plan_analysis import (PlanVerificationError, analyze_plan,
+                            catalog_dtypes, check_schema_preserved)
 from .planner import (OptimizedPlan, PlanCache, catalog_base_stats,
-                      catalog_schema, modeled_plan_cost, optimize)
+                      catalog_schema, modeled_plan_cost, optimize,
+                      prune_projections, scan_columns)
 from .runtime_filters import FilterCache
 from .strategies import FilteredStrategy, RelJoinStrategy, Strategy
 
@@ -83,8 +93,8 @@ class Submission:
 class SharedSubtree:
     """One deduped subtree: executed once, fanned out to its consumers."""
 
-    sig: str
-    node: Node
+    sig: str                    # signature of the subtree as submitted
+    node: Node                  # as executed: pruned to its consumers' union
     consumers: Tuple[str, ...]  # query names containing the subtree
     occurrences: int            # total occurrences across the batch (>= 2)
     result: ExecutionResult     # the single producer execution
@@ -97,6 +107,7 @@ class BatchReport:
     results: Dict[str, ExecutionResult]
     shared: List[SharedSubtree]
     wall_time_s: float
+    plans: Dict[str, Node]      # each query's plan as executed (pruned)
 
     @property
     def total_network_bytes(self) -> float:
@@ -220,11 +231,28 @@ class QueryService:
 
     def _optimize(self, plan: Node,
                   plan_cache: Optional[PlanCache] = None) -> OptimizedPlan:
-        # prune=False: projection pruning would specialize shared subtrees
-        # per consumer column set and defeat CSE (module docstring).
+        # prune=False: pruning waits for the batch (``_prune``), where the
+        # shared subtrees are known (module docstring).
         return optimize(plan, self.catalog, params=self._params,
                         prune=False, verify=self.verify,
                         plan_cache=plan_cache)
+
+    def _prune(self, plan: Node, required=None,
+               barriers: Optional[Dict[str, set]] = None) -> Node:
+        """``plan`` narrowed to ``required`` of its output (all of it by
+        default) and to what its operators read, shared subtrees in
+        ``barriers`` kept whole (``prune_projections``)."""
+        pruned = prune_projections(plan, self._schema, required, barriers)
+        obs.count("pruned_columns", scan_columns(plan, self._schema)
+                  - scan_columns(pruned, self._schema))
+        if self.verify:
+            violations = (check_schema_preserved(plan, pruned, self._schema,
+                                                 required=required)
+                          + analyze_plan(pruned, self._schema,
+                                         catalog_dtypes(self.catalog)))
+            if violations:
+                raise PlanVerificationError(violations)
+        return pruned
 
     def _executor(self, intermediates: Optional[Dict[str, Table]] = None
                   ) -> Executor:
@@ -246,54 +274,64 @@ class QueryService:
 
     def _execute_batch(self, batch: List[Submission]) -> BatchReport:
         t0 = time.perf_counter()
+        # Count every candidate occurrence across the batch (intra-query
+        # duplicates count too — two occurrences in one plan still share).
+        info: Dict[str, list] = {}
+        for sub in batch if self.cse else ():
+            for sig, node in shared_subtree_candidates(sub.optimized.plan):
+                entry = info.setdefault(sig, [node, 0, []])
+                entry[1] += 1
+                if sub.name not in entry[2]:
+                    entry[2].append(sub.name)
+        by_size = sorted((s for s, e in info.items() if e[1] >= 2),
+                         key=lambda s: subtree_size(info[s][0]))
+        # Consumers first, then producers largest-first: each records what
+        # it reads of the shared subtrees it holds, so a producer is pruned
+        # to the union of its consumers' columns.
+        needs: Dict[str, set] = {sig: set() for sig in by_size}
+        plans = [self._prune(sub.optimized.plan, barriers=needs)
+                 for sub in batch]
+        producers = {}
+        for sig in reversed(by_size):
+            need = needs.pop(sig)
+            producers[sig] = self._prune(info[sig][0], need or None,
+                                         barriers=needs)
+        # Producers run smallest-first so a shared subtree nested inside a
+        # larger shared subtree is already injectable when the larger one
+        # executes. Each table is stored under the signature its consumers
+        # still carry, that of the unpruned subtree.
         intermediates: Dict[str, Table] = {}
         shared: List[SharedSubtree] = []
-        if self.cse:
-            # Count every candidate occurrence across the batch (intra-query
-            # duplicates count too — two occurrences in one plan still share).
-            info: Dict[str, list] = {}
-            for sub in batch:
-                for sig, node in shared_subtree_candidates(
-                        sub.optimized.plan):
-                    entry = info.setdefault(sig, [node, 0, []])
-                    entry[1] += 1
-                    if sub.name not in entry[2]:
-                        entry[2].append(sub.name)
-            shared_sigs = [s for s, e in info.items() if e[1] >= 2]
-            # Producers run smallest-first so a shared subtree nested inside
-            # a larger shared subtree is already injectable when the larger
-            # one executes.
-            for sig in sorted(shared_sigs,
-                              key=lambda s: subtree_size(info[s][0])):
-                node, count, consumers = info[sig]
-                # The span carries a digest: signatures hold the ',' and
-                # '=' that separate a trace event's metadata.
-                digest = hashlib.blake2b(sig.encode(),
-                                         digest_size=6).hexdigest()
-                with obs.span("service.shared", sig=digest):
-                    res = self._executor(intermediates).execute(node)
-                intermediates[sig] = res.table
-                shared.append(SharedSubtree(sig, node, tuple(consumers),
-                                            count, res))
+        for sig in by_size:
+            _, count, consumers = info[sig]
+            # The span carries a digest: signatures hold the ',' and '='
+            # that separate a trace event's metadata.
+            digest = hashlib.blake2b(sig.encode(), digest_size=6).hexdigest()
+            with obs.span("service.shared", sig=digest):
+                res = self._executor(intermediates).execute(producers[sig])
+            intermediates[sig] = res.table
+            shared.append(SharedSubtree(sig, producers[sig], tuple(consumers),
+                                        count, res))
         results: Dict[str, ExecutionResult] = {}
-        for sub in batch:
+        for sub, plan in zip(batch, plans):
             with obs.span("service.query", query=sub.name):
                 results[sub.name] = self._executor(intermediates).execute(
-                    sub.optimized.plan)
-        return BatchReport(results, shared, time.perf_counter() - t0)
+                    plan)
+        return BatchReport(results, shared, time.perf_counter() - t0,
+                           {sub.name: p for sub, p in zip(batch, plans)})
 
     def execute_solo(self, query: Union[str, Node]) -> ExecutionResult:
         """Reference single-query execution: same optimizer settings, but
         no plan cache, no injected intermediates, and a *fresh* FilterCache
         — the result batched execution must reproduce."""
         plan = parse_sql(query) if isinstance(query, str) else query
-        optimized = self._optimize(plan)
+        pruned = self._prune(self._optimize(plan).plan)
         strategy = self.strategy
         if isinstance(strategy, FilteredStrategy):
             strategy = dataclasses.replace(strategy, cache=FilterCache())
         ex = Executor(self.catalog, strategy, adaptive=self.adaptive,
                       verify=True if self.verify else None)
-        return ex.execute(optimized.plan)
+        return ex.execute(pruned)
 
     # -- stats publish ---------------------------------------------------------
 

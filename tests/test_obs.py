@@ -104,6 +104,14 @@ def test_a_service_round_moves_the_counters(catalog):
     assert counters["exchange_bytes"] > 0
 
 
+def test_a_service_round_counts_pruned_columns(catalog):
+    """q1_star3 reads a few columns of each scan it joins, so the round
+    prunes the rest."""
+    assert "pruned_columns" in obs.COUNTERS
+    _, _, counters = _one_query(catalog, every_query()["q1_star3"])
+    assert counters["pruned_columns"] > 0
+
+
 def test_exchange_bytes_are_the_joins_network_bytes(catalog):
     """Without runtime filters (whose payload bytes ``network_bytes``
     adds) a join-only query's exchanges are what ``network_bytes``

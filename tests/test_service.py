@@ -12,11 +12,14 @@ below serial), and the admission/caching discipline.
 
 import pytest
 
+from repro import obs
 from repro.joins.ref import rows_as_set, rows_close
 from repro.sql import (AdmissionController, Aggregate, Join, PlanCache,
-                       QueryService, Scan, Submission, generate, optimize,
-                       parse_sql, service_queries,
+                       Project, QueryService, Scan, Submission, every_query,
+                       generate, optimize, parse_sql, service_queries,
                        shared_subtree_candidates, signature)
+from repro.sql.logical import leaf_columns
+from repro.sql.planner import catalog_schema
 from repro.sql.queries import SQL_TEXTS
 
 
@@ -92,6 +95,143 @@ def test_stats_publish(service_batch):
     assert stats["queries_submitted"] >= len(subs)
     assert stats["plan_cache_misses"] >= len(subs)
     assert stats["plan_cache_size"] == len(service.plan_cache)
+
+
+# ---------------------------------------------------------------------------
+# Projection pruning of the executed plans, shared subtrees as barriers
+# ---------------------------------------------------------------------------
+
+#: One star under two GROUP BYs on different dimensions: the join region
+#: below the Aggregate is the shared subtree.
+STAR_BY = """
+    SELECT {key}, SUM(ss_net_profit)
+    FROM store_sales
+    JOIN customer ON ss_customer_sk = c_customer_sk
+    JOIN (SELECT * FROM item WHERE i_category < 3) ON ss_item_sk = i_item_sk
+    GROUP BY {key}
+"""
+
+#: A shared aggregated subquery under two joins that differ, so the
+#: fact scans of its consumers lie outside every shared subtree.
+CROSS_CHANNEL = """
+    SELECT {key}, SUM({col})
+    FROM store_sales
+    JOIN (SELECT cs_item_sk, SUM(cs_sales_price), COUNT(cs_quantity)
+          FROM catalog_sales GROUP BY cs_item_sk)
+      ON ss_item_sk = cs_item_sk
+    {more}
+    GROUP BY {key}
+"""
+
+
+def _pruned_round(catalog, queries, cse=True):
+    """Run ``queries`` ({name: sql}) as one batch: (report, the round's
+    ``pruned_columns``)."""
+    service = QueryService(catalog, cse=cse, verify=True)
+    for name, sql in queries.items():
+        service.submit(sql, name=name)
+    before = obs.snapshot()
+    (report,) = service.run()
+    after = obs.snapshot()
+    return report, after["pruned_columns"] - before.get("pruned_columns", 0)
+
+
+def _scans_read(plan, barriers=()):
+    """{table: columns yielded} over the scans of ``plan`` outside the
+    subtrees whose signatures are in ``barriers``."""
+    if signature(plan) in barriers:
+        return {}
+    if isinstance(plan, Project) and isinstance(plan.child, Scan):
+        return {plan.child.table: set(plan.columns)}
+    if isinstance(plan, Scan):
+        return {plan.table: {"*"}}
+    out = {}
+    for child in plan.children():
+        out.update(_scans_read(child, barriers))
+    return out
+
+
+def test_shared_producer_carries_the_union_of_its_consumers_columns(
+        catalog):
+    queries = {"by_region": STAR_BY.format(key="c_region"),
+               "by_brand": STAR_BY.format(key="i_brand")}
+    report, pruned = _pruned_round(catalog, queries)
+    (shared,) = report.shared
+    assert shared.consumers == ("by_region", "by_brand")
+    assert set(leaf_columns(shared.node, catalog_schema(catalog))) == {
+        "ss_customer_sk", "ss_item_sk", "ss_net_profit", "c_customer_sk",
+        "c_region", "i_item_sk", "i_category", "i_brand"}
+    # The consumers hold the shared subtree whole and run no join of it.
+    for name in queries:
+        assert signature(report.plans[name].child) == shared.sig
+        assert report.results[name].decisions == []
+    schema = catalog_schema(catalog)
+    assert pruned == (len(schema["store_sales"]) - 3
+                      + len(schema["customer"]) - 2
+                      + len(schema["item"]) - 3)
+    solo = QueryService(catalog, verify=True)
+    for name, sql in queries.items():
+        assert rows_close(_rows(report.results[name]),
+                          _rows(solo.execute_solo(sql))), name
+
+
+def test_consumer_scans_outside_shared_subtrees_read_only_their_columns(
+        catalog):
+    queries = {
+        "by_store": CROSS_CHANNEL.format(key="ss_store_sk",
+                                         col="ss_sales_price", more=""),
+        "by_state": CROSS_CHANNEL.format(
+            key="s_state", col="ss_net_profit",
+            more="JOIN store ON ss_store_sk = s_store_sk")}
+    report, pruned = _pruned_round(catalog, queries)
+    (shared,) = report.shared
+    assert isinstance(shared.node, Aggregate)
+    sigs = {shared.sig}
+    assert _scans_read(report.plans["by_store"], sigs) == {
+        "store_sales": {"ss_item_sk", "ss_store_sk", "ss_sales_price"}}
+    assert _scans_read(report.plans["by_state"], sigs) == {
+        "store_sales": {"ss_item_sk", "ss_store_sk", "ss_net_profit"},
+        "store": {"s_store_sk", "s_state"}}
+    assert _scans_read(shared.node) == {
+        "catalog_sales": {"cs_item_sk", "cs_sales_price", "cs_quantity"}}
+    schema = catalog_schema(catalog)
+    assert pruned == (2 * (len(schema["store_sales"]) - 3)
+                      + len(schema["store"]) - 2
+                      + len(schema["catalog_sales"]) - 3)
+    solo = QueryService(catalog, verify=True)
+    for name, sql in queries.items():
+        assert rows_close(_rows(report.results[name]),
+                          _rows(solo.execute_solo(sql))), name
+
+
+def test_a_root_that_needs_every_column_prunes_nothing(catalog):
+    service = QueryService(catalog, verify=True)
+    sub = service.submit(every_query()["q1_star3"].child, name="q")
+    before = obs.snapshot()
+    (report,) = service.run()
+    after = obs.snapshot()
+    assert after["pruned_columns"] == before.get("pruned_columns", 0)
+    assert report.plans["q"] == sub.optimized.plan
+
+
+def test_without_cse_every_plan_is_pruned_fully(catalog):
+    queries = {"by_region": STAR_BY.format(key="c_region"),
+               "by_brand": STAR_BY.format(key="i_brand")}
+    report, pruned = _pruned_round(catalog, queries, cse=False)
+    assert report.shared == []
+    fact = {"ss_customer_sk", "ss_item_sk", "ss_net_profit"}
+    assert _scans_read(report.plans["by_region"]) == {
+        "store_sales": fact, "customer": {"c_customer_sk", "c_region"},
+        "item": {"i_item_sk", "i_category"}}
+    assert _scans_read(report.plans["by_brand"]) == {
+        "store_sales": fact, "customer": {"c_customer_sk"},
+        "item": {"i_item_sk", "i_category", "i_brand"}}
+    schema = catalog_schema(catalog)
+    assert pruned == (2 * (len(schema["store_sales"]) - 3)
+                      + len(schema["customer"]) - 2
+                      + len(schema["customer"]) - 1
+                      + len(schema["item"]) - 2
+                      + len(schema["item"]) - 3)
 
 
 # ---------------------------------------------------------------------------
